@@ -47,10 +47,12 @@ def run_rows():
             f"iters={int(r.iterations)};"
             f"per_iter_us={m.median / max(int(r.iterations), 1):.0f}"
         ))
-    # communication-volume strong scaling (per AS iteration, per device)
+    # communication-volume strong scaling (per AS iteration, per device).
+    # The children compile for placeholder host devices only; pinning them
+    # to the CPU keeps them off the chip this (parent) process holds.
     n, m = 1 << 20, (1 << 20) * 8
     for (rr, cc) in [(1, 1), (2, 2), (4, 4), (8, 8)]:
-        env = dict(os.environ, PYTHONPATH="src",
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={rr*cc}")
         res = subprocess.run([sys.executable, "-c", _CHILD,
                               str(rr), str(cc), str(n), str(m)],

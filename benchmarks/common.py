@@ -160,26 +160,30 @@ def assert_msf_parity(ref, other, what: str) -> None:
 
 
 def cost_fragment(rep, t_s: float) -> str:
-    """Measured-vs-roofline derived fields from ``SolveReport.cost``.
+    """Analytic-count and device-rate derived fields from
+    ``SolveReport.cost``.
 
     ``flops``/``hbm_bytes`` are the analytic counts of the plan's
-    executable (× iterations when the convergence loop is dynamic);
-    ``roofline_frac`` is the analytic bound time over the measured time
-    on the reference accelerator (TPU v5e constants — on the CPU
-    container it reads as "how far this run is from the modeled chip",
-    the dry-run story of EXPERIMENTS.md §Roofline)."""
+    executable (× iterations when the convergence loop is dynamic). The
+    rates — ``gflops_per_s`` and ``roofline_frac`` (analytic bound time
+    over the measured time) — are device metrics: they are reported only
+    on an accelerator, against the peaks of its ``device_kind``
+    (``repro.analysis.roofline.peaks``, which raises for an unknown
+    kind), and left out of a CPU run."""
     c = getattr(rep, "cost", None)
     if c is None or t_s <= 0:
         return ""
     mult = max(int(rep.iterations), 1) if c.dynamic_loops else 1
     flops, byts = c.flops * mult, c.bytes * mult
-    from repro.analysis.roofline import TPU_V5E
+    out = f";flops={flops:.4g};hbm_bytes={byts:.4g}"
+    if jax.default_backend() == "cpu":
+        return out
+    from repro.analysis.roofline import peaks
 
-    bound_s = max(flops / TPU_V5E["peak_flops_bf16"],
-                  byts / TPU_V5E["hbm_bw"])
+    hw = peaks()
+    bound_s = max(flops / hw["peak_flops_bf16"], byts / hw["hbm_bw"])
     return (
-        f";flops={flops:.4g};hbm_bytes={byts:.4g}"
-        f";gflops_per_s={flops / t_s / 1e9:.3f}"
+        out + f";gflops_per_s={flops / t_s / 1e9:.3f}"
         f";roofline_frac={bound_s / t_s:.2e}"
     )
 
@@ -191,6 +195,7 @@ def env_fingerprint() -> dict:
     return {
         "jax": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
         "python": platform.python_version(),
         "machine": platform.machine(),

@@ -28,6 +28,9 @@ def _slug(label: str) -> str:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_coarsen,
         bench_graph_suite,

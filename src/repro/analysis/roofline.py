@@ -19,8 +19,9 @@ Dynamic-trip-count loops (the MSF engine's convergence loop) are flagged:
 their numbers are per loop iteration — the paper's own reporting unit
 (time *per iteration*, Fig 3/4).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants come from :data:`PEAKS`, keyed by the
+``device_kind`` JAX reports. A device that is not in the table is an
+error, never a default.
 """
 from __future__ import annotations
 
@@ -28,15 +29,46 @@ from typing import Dict
 
 from repro.analysis.hlo_analyzer import analyze
 
-TPU_V5E = dict(
-    peak_flops_bf16=197e12,  # per chip
-    hbm_bw=819e9,  # B/s
-    ici_bw=50e9,  # B/s per link
-)
+#: Published per-chip peaks by ``jax.devices()[0].device_kind``.
+#: "TPU v5 lite" (TPU v5e): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip
+#: interconnect (4 links of ~50 GB/s).
+PEAKS = {
+    "TPU v5 lite": dict(
+        peak_flops_bf16=197e12,  # per chip
+        hbm_bw=819e9,  # B/s
+        hbm_bytes=16e9,
+        ici_bw=50e9,  # B/s per link
+    ),
+}
+
+#: The chip the analytic projections (the autotuner's pruning model,
+#: the dry-run roofline) are made for when no such chip is attached.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str | None = None) -> Dict:
+    """Peak rates of ``device_kind`` (default: the first JAX device's).
+    Raises ``KeyError`` for a kind with no published entry — a CPU run
+    has no device peaks to compare against."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
 
 
 def roofline(compiled, *, n_devices: int, model_flops: float | None = None,
-             hw: Dict = TPU_V5E) -> Dict:
+             hw: Dict | None = None) -> Dict:
+    """Roofline terms of ``compiled`` against ``hw`` (default: the peaks
+    of the device this process runs on, via :func:`peaks`)."""
+    hw = peaks() if hw is None else hw
     ca = compiled.cost_analysis() or {}
     res = analyze(compiled.as_text())
     flops = max(float(res["flops"]), float(ca.get("flops", 0.0)))

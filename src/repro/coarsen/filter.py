@@ -11,19 +11,18 @@ All-device, single jitted call with static shapes:
 
 1. canonical pair keys — packed uint32 ``lo << 16 | hi`` when n ≤ 2^16,
    the (lo, hi) pair beyond (int64 keys are unavailable without
-   jax_enable_x64) — lexsorted with (w, eid) as trailing keys so each
-   pair run leads with its (w, eid)-lex minimum;
+   jax_enable_x64) — sorted on the pair key alone;
 2. sort → duplicate pairs become adjacent; segment ids by boundary-flag
    prefix-sum (≤ E segments, independent of n′² — invalid entries sort
    last into one dead segment, so live segments are already
    front-compacted);
 3. per-segment MINWEIGHT via the pack32 segment-min in the
-   integer-weight regime, the 3-pass masked float reduction
+   integer-weight regime, the 3-pass masked float (w, eid) reduction
    (``semiring.segment_argmin``) otherwise. The segment ids here are
    *sorted* (a prefix-sum over sort-order boundary flags), so the
    matching Pallas backend is ``kernels.segment_min_sorted`` — O(E)
-   lanes via scalar-prefetched per-row-block offsets, vs the flat
-   kernel's O(E²/block_rows) rescan at ``num_segments = E``
+   compares via scalar-prefetched per-row-block offsets, vs the flat
+   kernel's O(E²) rescan at ``num_segments = E``
    (``segmin=None``/"jnp" keeps this step at O(E) via segment_min);
 4. gather the winners' (lo, hi, w, global eid).
 
@@ -193,24 +192,24 @@ def filter_level_impl(
             m_new=jnp.sum(seg_live.astype(jnp.int32)),
         )
 
-    # Float path: sort by (pair key, w, eid) so within each pair run the
-    # (w, eid)-lex minimum comes first and the min-*position* winner IS
-    # the representative — position alone would tie-break equal weights
-    # by array order, which stops tracking eid order after the first
-    # level.
+    # Float path: the sort only makes duplicate pairs adjacent, carrying
+    # the permutation; the MINWEIGHT reduction then picks each pair's
+    # (w, eid)-lex minimum by value (eids are distinct). Keeping (w, eid)
+    # out of the sort keys matters on TPU, where XLA's sort compiles in
+    # time that grows with its operand count (minutes for the old
+    # four-key lexsort).
+    pos = jnp.arange(e, dtype=jnp.int32)
     if n <= PAIR_PACK_LIMIT:
         key = (lo.astype(jnp.uint32) << 16) | hi.astype(jnp.uint32)
         key = jnp.where(real, key, jnp.uint32(0xFFFFFFFF))
-        order = jnp.lexsort((eid, w, key))
-        key_s = key[order]
+        key_s, order = jax.lax.sort((key, pos), num_keys=1)
         boundary = jnp.concatenate(
             [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]]
         )
     else:
         lo_k = jnp.where(real, lo, jnp.int32(n))
         hi_k = jnp.where(real, hi, jnp.int32(n))
-        order = jnp.lexsort((eid, w, hi_k, lo_k))
-        lo_ks, hi_ks = lo_k[order], hi_k[order]
+        lo_ks, hi_ks, order = jax.lax.sort((lo_k, hi_k, pos), num_keys=2)
         boundary = jnp.concatenate(
             [
                 jnp.ones((1,), bool),
@@ -221,10 +220,9 @@ def filter_level_impl(
     w_s, eid_s = w[order], eid[order]
     real_s = real[order]
     seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1  # [0, E) ranks
-    pos = jnp.arange(e, dtype=jnp.int32)
 
-    em = segment_argmin(w_s, pos, (), seg, e, valid=real_s)
-    winner = em.eid
+    em = segment_argmin(w_s, eid_s, (pos,), seg, e, valid=real_s)
+    winner = em.payload[0]
     seg_live = em.w < INF
 
     sel = jnp.clip(winner, 0, e - 1)

@@ -1,60 +1,18 @@
-"""jax version compatibility (single import point).
+"""Mesh helpers shared by the distributed engines and their tests.
 
-The codebase targets the modern spellings — ``jax.shard_map`` and
-``jax.make_mesh(..., axis_types=...)``. Older jax (< 0.5, e.g. the 0.4.x
-CPU wheels in CI containers) has ``shard_map`` under ``jax.experimental``
-and no ``AxisType``/``axis_types`` (Auto is the implicit behavior there,
-so the fallback is semantics-preserving). Import both names from here.
+``shard_map`` is re-exported from jax so call sites keep one import
+point; :func:`make_mesh` pins the Auto axis types the shard_map engines
+are written for (sharding propagates through ``jit`` around them).
 """
 from __future__ import annotations
 
 import jax
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.5: experimental home, and check_vma was named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=check_vma,
-            **kw,
-        )
-
-
-@jax.custom_jvp
-def optimization_barrier(x):
-    """Differentiable ``lax.optimization_barrier``.
-
-    jax < 0.5 has no differentiation rule for the primitive; this wrapper
-    keeps the barrier on the primal (the scheduling pin is all we want)
-    and passes tangents through untouched, which transposes cleanly for
-    reverse mode on every version.
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@optimization_barrier.defjvp
-def _optimization_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return optimization_barrier(x), t
+from jax import shard_map  # noqa: F401 — re-exported
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types when supported.
-
-    Floor note: ``jax.make_mesh`` itself exists from 0.4.35 — the
-    requirements/CI floor — so only the ``axis_types`` spelling needs a
-    fallback here.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):  # jax < 0.5: Auto is implicit
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

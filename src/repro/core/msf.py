@@ -283,7 +283,8 @@ def _msf_traced(
 
 def run_flat(graph: Graph, **kw) -> MSFResult:
     """Flat-driver dispatch for callers holding a *resolved* segmin
-    callable (the ``repro.solve`` flat engine, :func:`flat_msf`):
+    callable (the ``repro.solve`` flat engine, :func:`flat_msf`, the
+    stream union solve):
     the jitted while_loop driver normally, the span-per-round host
     driver when obs trace mode is active."""
     from repro import obs
@@ -297,7 +298,7 @@ def flat_msf(graph: Graph, *, pack: bool = False, segmin: str | None = None,
              **kw) -> MSFResult:
     """Internal flat AS solve — the non-deprecated twin of the old
     ``msf()`` kwarg path, used by the ``repro.solve`` engines and the
-    residual/union solves of the coarsen and stream stacks.
+    coarsen stack's residual solve.
 
     ``segmin`` is the *string* backend request; resolution (including
     the "sorted"-degrades-to-"auto" rule for unsorted hook segments)

@@ -176,7 +176,7 @@ def nx_free_msf_weight(graph: Graph) -> float:
     src, dst, w = src[valid], dst[valid], w[valid]
     a = sp.coo_matrix((w, (src, dst)), shape=(graph.n, graph.n)).tocsr()
     t = csg.minimum_spanning_tree(a)
-    return float(t.sum())
+    return float(t.data.sum(dtype=np.float64))  # exact for integer weights
 
 
 def nx_free_n_components(graph: Graph) -> int:

@@ -5,10 +5,10 @@ Computes, per row i: the MINWEIGHT-monoid reduction
 with payload p_j — i.e. Algorithm 1 line 9 with f(p_i, a_ij, p_j).
 
 TPU mapping (DESIGN.md §2): grid = (rows/BI, cols/BJ) with the column
-dimension innermost and *sequential*; the (BI,) running accumulators live in
+dimension innermost and *sequential*; the (BI, 1) running accumulators live in
 the output VMEM blocks, which Pallas revisits for every j because their
 index_map ignores j. Each grid step loads an (BI, BJ) tile of A and the
-(BI,)/(BJ,) slabs of p — a VPU compare/select + min-reduce over lanes, the
+(BI, 1)/(1, BJ) slabs of p — a VPU compare/select + min-reduce over lanes, the
 all-at-once form of the kernel (no materialized (a_ij, p_j) pairs, which is
 exactly the paper's complaint about the pairwise SpMV formulation).
 """
@@ -34,20 +34,21 @@ def _kernel(x_ref, y_ref, a_ref, minw_ref, mincol_ref, minpay_ref, *, block_j):
         mincol_ref[...] = jnp.full_like(mincol_ref, IMAX)
         minpay_ref[...] = jnp.full_like(minpay_ref, IMAX)
 
-    x = x_ref[...]  # [BI] int32 (p row slab)
-    y = y_ref[...]  # [BJ] int32 (p col slab)
+    x = x_ref[...]  # [BI, 1] int32 (p row slab, a column)
+    y = y_ref[...]  # [1, BJ] int32 (p col slab, a row)
     a = a_ref[...]  # [BI, BJ] f32
     col = j_blk * block_j + jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
 
-    valid = (x[:, None] != y[None, :]) & (a < INF)
+    valid = (x != y) & (a < INF)
     w = jnp.where(valid, a, INF)
-    bw = jnp.min(w, axis=1)
-    on = (w == bw[:, None]) & (bw[:, None] < INF)
-    bcol = jnp.min(jnp.where(on, col, IMAX), axis=1)
-    winner = on & (col == bcol[:, None])
+    bw = jnp.min(w, axis=1, keepdims=True)
+    on = (w == bw) & (bw < INF)
+    bcol = jnp.min(jnp.where(on, col, IMAX), axis=1, keepdims=True)
+    winner = on & (col == bcol)
     bpay = jnp.min(
-        jnp.where(winner, jnp.broadcast_to(y[None, :], a.shape).astype(jnp.int32), IMAX),
+        jnp.where(winner, jnp.broadcast_to(y, a.shape), IMAX),
         axis=1,
+        keepdims=True,
     )
 
     # MINWEIGHT combine with the running accumulator (lexicographic (w, col)).
@@ -66,36 +67,45 @@ def _kernel(x_ref, y_ref, a_ref, minw_ref, mincol_ref, minpay_ref, *, block_j):
 
 
 def multilinear_dense_pallas(
-    p: jax.Array,
+    p_rows: jax.Array,
+    p_cols: jax.Array,
     a: jax.Array,
     *,
     block_i: int = 128,
     block_j: int = 128,
     interpret: bool = False,
 ):
-    """p: int32 [n]; a: f32 [n, n] with +inf for absent edges. n must be a
-    multiple of the block sizes (``ops.multilinear_dense`` pads)."""
-    n = a.shape[0]
-    assert n % block_i == 0 and a.shape[1] % block_j == 0
-    grid = (n // block_i, a.shape[1] // block_j)
+    """p_rows: int32 [n_i] (row payloads), p_cols: int32 [n_j] (column
+    payloads); a: f32 [n_i, n_j] with +inf for absent edges. n_i and n_j
+    must be multiples of the block sizes (``ops.multilinear_dense`` pads).
+
+    TPU tiling: the row payloads and the three outputs are [n_i, 1]
+    columns in (block_i, 1) blocks and the column payloads a [1, n_j] row
+    in (1, block_j) blocks, so every block is 2-D with its short side the
+    whole array dimension.
+    """
+    n_i, n_j = a.shape
+    if n_i % block_i or n_j % block_j or block_i % 8 or block_j % 128:
+        raise ValueError(
+            f"a {a.shape} must tile by blocks ({block_i}, {block_j}), with "
+            f"block_i a multiple of 8 and block_j a multiple of 128"
+        )
     kernel = functools.partial(_kernel, block_j=block_j)
-    return pl.pallas_call(
+    col_block = pl.BlockSpec((block_i, 1), lambda i, j: (i, 0))
+    outs = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_i // block_i, n_j // block_j),
         in_specs=[
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-            pl.BlockSpec((block_j,), lambda i, j: (j,)),
+            col_block,
+            pl.BlockSpec((1, block_j), lambda i, j: (0, j)),
             pl.BlockSpec((block_i, block_j), lambda i, j: (i, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-        ],
+        out_specs=[col_block, col_block, col_block],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n_i, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_i, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_i, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(p, p, a)
+    )(p_rows.reshape(n_i, 1), p_cols.reshape(1, n_j), a)
+    return tuple(o.reshape(n_i) for o in outs)

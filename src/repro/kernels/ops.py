@@ -1,8 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced JAX ops, validating the exact TPU program
-logic. On a TPU backend they compile to Mosaic.
+On a TPU backend the kernels compile to Mosaic. On any other backend
+(the CPU test runs) they execute in ``interpret=True`` mode — the kernel
+body runs as traced JAX ops, checking the TPU program's logic but not
+its tiling, which only the TPU compiler checks
+(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -14,6 +16,7 @@ import numpy as np
 
 from repro.kernels.multilinear_dense import multilinear_dense_pallas
 from repro.kernels.segment_min_bucketed import (
+    LANES,
     segment_min_bucketed_pallas,
     segment_min_flat_pallas,
 )
@@ -28,6 +31,13 @@ def _use_interpret(interpret):
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
+
+
+def _padded(size: int, block: int) -> int:
+    """``size`` rounded up to whole blocks — or, below one block, to
+    whole 128-lane rows, which the kernels take as one smaller block."""
+    step = block if size > block else LANES
+    return max(LANES, -(-size // step) * step)
 
 
 @partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
@@ -50,11 +60,12 @@ def multilinear_dense(
     n_i = -(-n // bi) * bi
     n_j = -(-n // bj) * bj
     a_p = jnp.full((n_i, n_j), INF, jnp.float32).at[:n, :n].set(a)
-    # Padded vertices get unique negative ids so p_i != p_j never matches
-    # spuriously... they must *never* be selected: a = inf handles that.
-    p_pad_i = jnp.full((n_i,), -1, jnp.int32).at[:n].set(p.astype(jnp.int32))
+    # Padded vertices carry payload -1; they are never selected because
+    # their a entries are +inf.
+    p32 = p.astype(jnp.int32)
     minw, mincol, minpay = multilinear_dense_pallas(
-        p_pad_i,
+        jnp.full((n_i,), -1, jnp.int32).at[:n].set(p32),
+        jnp.full((n_j,), -1, jnp.int32).at[:n].set(p32),
         a_p,
         block_i=bi,
         block_j=bj,
@@ -85,19 +96,20 @@ def segment_min_flat(
     segs: jax.Array,
     *,
     num_segments: int,
-    block_rows: int = 128,
-    block_edges: int = 512,
+    block_rows: int = 1024,
+    block_edges: int = 1024,
     interpret: bool | None = None,
 ):
     """Flat packed-key segment-min over arbitrary (unsorted) segment ids.
 
     Pads the edge dimension to a block_edges multiple (identity keys) and
-    the segment dimension to a block_rows multiple, then slices back — the
-    caller keeps natural shapes.
+    the segment dimension to a block_rows multiple — each to a 128
+    multiple when it fits one block — then slices back: the caller keeps
+    natural shapes.
     """
     e = keys.shape[0]
-    e_pad = max(block_edges, -(-e // block_edges) * block_edges)
-    s_pad = max(block_rows, -(-num_segments // block_rows) * block_rows)
+    e_pad = _padded(e, block_edges)
+    s_pad = _padded(num_segments, block_rows)
     keys_p = jnp.full((e_pad,), UMAX, jnp.uint32).at[:e].set(keys)
     segs_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(segs)
     out = segment_min_flat_pallas(
@@ -120,22 +132,22 @@ def segment_min_sorted(
     segs: jax.Array,
     *,
     num_segments: int,
-    block_rows: int = 128,
-    block_edges: int = 512,
+    block_rows: int = 1024,
+    block_edges: int = 1024,
     interpret: bool | None = None,
 ):
     """Contiguous-range packed segment-min over **sorted** segment ids.
 
     Same pad-and-slice contract as :func:`segment_min_flat`, but the
     kernel scalar-prefetches per-row-block edge-block offsets so each
-    grid step reads only the blocks its segments touch — O(E) lanes for
-    the coarsening dedupe where the flat kernel is O(E²/block_rows).
+    grid step reads only the blocks its segments touch — O(E) compares
+    for the coarsening dedupe where the flat kernel is O(E²).
     Padding entries get segment id ``num_segments_padded − 1`` (identity
     keys), preserving sortedness and covering the tail row block.
     """
     e = keys.shape[0]
-    e_pad = max(block_edges, -(-e // block_edges) * block_edges)
-    s_pad = max(block_rows, -(-num_segments // block_rows) * block_rows)
+    e_pad = _padded(e, block_edges)
+    s_pad = _padded(num_segments, block_rows)
     keys_p = jnp.full((e_pad,), UMAX, jnp.uint32).at[:e].set(keys)
     segs_p = jnp.full((e_pad,), s_pad - 1, jnp.int32).at[:e].set(segs)
     out = segment_min_sorted_pallas(
@@ -157,7 +169,7 @@ def dedupe_segmin_backend(backend: str | None):
     Returns the packed-segmin callable to pass to the filter, or ``None``
     for the plain XLA ``segment_min``: a Pallas request ("pallas"/"sorted")
     selects the contiguous-range sorted kernel (the flat kernel's full
-    rescan is O(E²/block_rows) at num_segments = E and was never viable
+    rescan is O(E²) at num_segments = E and was never viable
     here); "jnp" pins XLA; None/"auto" picks the sorted kernel on TPU and
     XLA elsewhere (interpreted Pallas loses badly to XLA on CPU). The
     single home of that rule — call sites must not re-implement it.

@@ -4,8 +4,8 @@ The coarsening dedupe (``repro.coarsen.filter``) produces segment ids by
 a boundary-flag prefix-sum over the *sorted* pair keys, so ``segs`` is
 non-decreasing and every segment occupies one contiguous edge range. The
 flat kernel (``segment_min_flat_pallas``) ignores that structure and
-rescans every edge block for every output row block — O(E²/block_rows)
-lanes at ``num_segments = E``. This kernel exploits it:
+rescans every edge block for every output row block — O(E²) compares
+at ``num_segments = E``. This kernel exploits it:
 
 - Each output row block ``rb`` covers segments
   ``[rb·block_rows, (rb+1)·block_rows)``; sortedness means those
@@ -17,10 +17,12 @@ lanes at ``num_segments = E``. This kernel exploits it:
   per-row-block edge-block offsets are **scalar-prefetched**
   (``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index maps DMA
   exactly the blocks each step touches and nothing else.
-- The output tile stays VMEM-resident across a row block's consecutive
-  steps and accumulates with ``min`` (first touch initializes to the
-  identity); steps padded beyond the live pair count re-reduce the final
-  pair, which is idempotent under min.
+- The row block's VMEM accumulator (the tiling of
+  ``segment_min_bucketed``) persists across its consecutive steps and
+  accumulates with ``min``: the first touch initializes it to the
+  identity, the last writes the lane-dense output tile. Steps padded
+  beyond the live pair count re-reduce the final pair, which is
+  idempotent under min.
 
 Keys are the pack32 layout (``repro.core.semiring``), identity/padding
 = 0xFFFFFFFF. Correctness does NOT require masking boundary blocks: an
@@ -34,17 +36,21 @@ unsorted ids want ``segment_min_flat_pallas``.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.segment_min_bucketed import _validate_blocked
-
-UMAX = np.uint32(0xFFFFFFFF)
+from repro.kernels.segment_min_bucketed import (
+    LANES,
+    _accumulate,
+    _finalize,
+    _init,
+    _validate_blocked,
+    check_flat_layout,
+    from_ordered_i32,
+    to_ordered_i32,
+)
 
 
 def build_step_maps(
@@ -93,25 +99,21 @@ def build_step_maps(
     return rb_map, eb_map.astype(jnp.int32)
 
 
-def _sorted_kernel(
-    rb_map_ref, eb_map_ref, keys_ref, segs_ref, out_ref, *, block_rows, block_edges
-):
+def _sorted_kernel(rb_map_ref, eb_map_ref, keys_ref, segs_ref, out_ref, acc_ref):
     s = pl.program_id(0)
+    last = pl.num_programs(0) - 1
     rb = rb_map_ref[s]
 
-    first = jnp.logical_or(s == 0, rb_map_ref[jnp.maximum(s - 1, 0)] != rb)
+    @pl.when(jnp.logical_or(s == 0, rb_map_ref[jnp.maximum(s - 1, 0)] != rb))
+    def _():
+        _init(acc_ref)
 
-    @pl.when(first)
-    def _init():
-        out_ref[...] = jnp.full((block_rows,), UMAX, jnp.uint32)
+    # Out-of-block segments match no local row.
+    _accumulate(keys_ref, segs_ref, acc_ref, rb * acc_ref.shape[0])
 
-    keys = keys_ref[0, :]  # [BE] uint32
-    segs = segs_ref[0, :]  # [BE] int32 sorted global segment ids
-    local = segs - rb * block_rows
-    r = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_edges), 0)
-    eq = local[None, :] == r  # out-of-block segments match no local row
-    vals = jnp.where(eq, keys[None, :], UMAX)
-    out_ref[...] = jnp.minimum(out_ref[...], jnp.min(vals, axis=1))
+    @pl.when(jnp.logical_or(s == last, rb_map_ref[jnp.minimum(s + 1, last)] != rb))
+    def _():
+        _finalize(acc_ref, out_ref)
 
 
 def segment_min_sorted_pallas(
@@ -119,8 +121,8 @@ def segment_min_sorted_pallas(
     segs: jax.Array,
     *,
     num_segments: int,
-    block_rows: int = 128,
-    block_edges: int = 512,
+    block_rows: int = 1024,
+    block_edges: int = 1024,
     interpret: bool = False,
 ):
     """Sorted-segment packed segment-min: keys uint32 [E], segs int32 [E]
@@ -131,57 +133,39 @@ def segment_min_sorted_pallas(
     ``block_edges``, ``num_segments`` a multiple of ``block_rows``; callers
     pad via ``kernels.ops.segment_min_sorted``); cost is
     O((E/block_edges + num_segments/block_rows) · block_rows·block_edges)
-    lanes instead of the flat kernel's O(num_segments·E/block_rows).
+    compares instead of the flat kernel's O(num_segments·E).
     """
     _validate_blocked(keys, segs, block_rows)
     if keys.ndim != 1:
         raise ValueError(f"expected flat [E] layout, got {keys.shape}")
-    if block_edges % 128:
-        raise ValueError(f"block_edges={block_edges} must be a multiple of 128 lanes")
-    if block_rows % 128:
-        raise ValueError(
-            f"block_rows={block_rows} must be a multiple of 128 (1-D output tile)"
-        )
     e = keys.shape[0]
-    if e == 0:
-        raise ValueError("empty edge array; pad to >= one block of edges")
-    if e % block_edges:
-        raise ValueError(
-            f"edge count {e} must be a multiple of block_edges={block_edges} "
-            f"(pad with identity keys)"
-        )
-    if num_segments <= 0 or num_segments % block_rows:
-        raise ValueError(
-            f"num_segments={num_segments} must be a positive multiple of "
-            f"block_rows={block_rows} (pad the output)"
-        )
-    ne = e // block_edges
+    br, be = check_flat_layout(e, num_segments, block_rows, block_edges)
+    ne = e // be
     rb_map, eb_map = build_step_maps(
         segs,
         num_segments=num_segments,
-        block_rows=block_rows,
-        block_edges=block_edges,
-    )
-    kernel = functools.partial(
-        _sorted_kernel, block_rows=block_rows, block_edges=block_edges
+        block_rows=br,
+        block_edges=be,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(ne + num_segments // block_rows,),
+        grid=(ne + num_segments // br,),
         in_specs=[
-            pl.BlockSpec((1, block_edges), lambda s, rbm, ebm: (ebm[s], 0)),
-            pl.BlockSpec((1, block_edges), lambda s, rbm, ebm: (ebm[s], 0)),
+            pl.BlockSpec((be // LANES, LANES), lambda s, rbm, ebm: (ebm[s], 0)),
+            pl.BlockSpec((be // LANES, LANES), lambda s, rbm, ebm: (ebm[s], 0)),
         ],
-        out_specs=pl.BlockSpec((block_rows,), lambda s, rbm, ebm: (rbm[s],)),
+        out_specs=pl.BlockSpec((br // LANES, LANES), lambda s, rbm, ebm: (rbm[s], 0)),
+        scratch_shapes=[pltpu.VMEM((br, LANES), jnp.int32)],
     )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        _sorted_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_segments,), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((num_segments // LANES, LANES), jnp.int32),
         interpret=interpret,
     )(
         rb_map,
         eb_map,
-        keys.reshape(ne, block_edges),
-        segs.reshape(ne, block_edges),
+        to_ordered_i32(keys).reshape(e // LANES, LANES),
+        segs.reshape(e // LANES, LANES),
     )
+    return from_ordered_i32(out.reshape(num_segments))

@@ -46,7 +46,7 @@ def main():
     args = ap.parse_args()
 
     import jax
-    from repro.analysis.roofline import roofline
+    from repro.analysis.roofline import TARGET_DEVICE_KIND, peaks, roofline
     from repro.configs import registry
     from repro.configs.base import MSF_SHAPES
     from repro.launch.cells import build_cell, build_msf_cell, lower_cell
@@ -91,8 +91,12 @@ def main():
                 lowered = lower_cell(cell)
                 compiled = lowered.compile()
                 mem = compiled.memory_analysis()
+                # an analytic projection onto the target chip, not a
+                # measurement: the cells compile for placeholder devices
                 rf = roofline(
-                    compiled, n_devices=n_dev, model_flops=cell.meta.get("model_flops")
+                    compiled, n_devices=n_dev,
+                    model_flops=cell.meta.get("model_flops"),
+                    hw=peaks(TARGET_DEVICE_KIND),
                 )
                 rec = dict(
                     cell=cell_id, arch=arch, shape=shape, mesh=mesh_name,

@@ -151,6 +151,9 @@ def _serve_main(argv) -> int:
 # ---------------------------------------------------------------------------
 
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if "--loadgen" in argv:
         from repro.launch.loadgen import main as loadgen_main

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import optimization_barrier, shard_map
+from repro.compat import shard_map
 from repro.configs.base import LMConfig
 from repro.models.layers import decode_attention, flash_attention, rms_norm, rope
 
@@ -323,7 +323,7 @@ def lm_forward(params, tokens, cfg: LMConfig, mesh, *, triangle_skip=False):
         # the saved activation out of the backward loop, materializing the
         # whole [L, B, S, d] stack in f32 (2× remat memory; 107 GiB for
         # kimi-k2). The barrier pins the convert inside the loop body.
-        x = optimization_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         lp = _constrain_layer(lp, cfg, mesh)
         h = attention_block(
             rms_norm(x, lp["ln1"], cfg.norm_eps), lp, cfg, positions,

@@ -59,9 +59,9 @@ class PlanCost(NamedTuple):
 def predicted_time_s(
     cost: Optional[PlanCost], *, iterations: int = 1
 ) -> Optional[float]:
-    """Analytic roofline time of a plan's executable on the reference
-    accelerator (TPU v5e constants — the same chip every bench row's
-    ``roofline_frac`` is quoted against), in seconds.
+    """Analytic roofline time of a plan's executable on the chip named by
+    ``analysis.roofline.TARGET_DEVICE_KIND``, in seconds — a model, never
+    a measurement.
 
     Per-iteration costs (``dynamic_loops > 0``) are multiplied by the
     ``iterations`` hint. This is the autotuner's pre-measurement pruning
@@ -71,12 +71,13 @@ def predicted_time_s(
     """
     if cost is None:
         return None
-    from repro.analysis.roofline import TPU_V5E
+    from repro.analysis.roofline import TARGET_DEVICE_KIND, peaks
 
+    hw = peaks(TARGET_DEVICE_KIND)
     mult = max(int(iterations), 1) if cost.dynamic_loops else 1
     return mult * max(
-        cost.flops / TPU_V5E["peak_flops_bf16"],
-        cost.bytes / TPU_V5E["hbm_bw"],
+        cost.flops / hw["peak_flops_bf16"],
+        cost.bytes / hw["hbm_bw"],
     )
 
 
@@ -92,12 +93,6 @@ def _from_analysis(c: dict, analyzed: str) -> PlanCost:
     )
 
 
-def _analyze_lowered(lowered, analyzed: str) -> PlanCost:
-    from repro.analysis.hlo_analyzer import analyze
-
-    return _from_analysis(analyze(lowered.compile().as_text()), analyzed)
-
-
 def _abstract(shape, dtype):
     import jax
 
@@ -105,10 +100,10 @@ def _abstract(shape, dtype):
 
 
 # ---------------------------------------------------------------------------
-# per-mode analyses
+# per-mode lowerings
 # ---------------------------------------------------------------------------
 
-def _flat_cost(n: int, e: int, rs) -> PlanCost:
+def _lower_flat(n: int, e: int, rs):
     from repro.core.msf import _msf_jit
     from repro.graphs.structures import Graph
 
@@ -131,14 +126,13 @@ def _flat_cost(n: int, e: int, rs) -> PlanCost:
         pack=bool(rs.pack),
         segmin=rs.segmin_flat,
     )
-    return _analyze_lowered(lowered, "flat")
+    return lowered, "flat"
 
 
-def _coarsen_cost(target, rs) -> PlanCost:
+def _lower_coarsen(target, rs):
     from repro.coarsen.engine import (
         _canonical_host,
         _eid_capacity,
-        _next_pow2,
         fused_level,
     )
     from repro.coarsen.contract import contract_level_und
@@ -150,7 +144,7 @@ def _coarsen_cost(target, rs) -> PlanCost:
     lo, hi, w, eid, valid, m0 = _canonical_host(target)
     if n0 <= cfg.cutoff or m0 == 0:
         # no levels run — the whole solve is the flat residual
-        return _flat_cost(n0, int(np.asarray(target.src).shape[0]), rs)
+        return _lower_flat(n0, int(np.asarray(target.src).shape[0]), rs)
 
     use_pack = bool(rs.pack)
     segmin_hook, segmin_dedupe = resolve_level_segmins(cfg.segmin, use_pack)
@@ -172,13 +166,28 @@ def _coarsen_cost(target, rs) -> PlanCost:
             pack=use_pack, segmin=segmin_hook, segmin_dedupe=segmin_dedupe,
             dedupe_host=resolve_dedupe(cfg.dedupe) == "host",
         )
-        return _analyze_lowered(lowered, "coarsen.level0.fused")
+        return lowered, "coarsen.level0.fused"
     lowered = contract_level_und.lower(
         *args,
         n=n_pad, eid_capacity=eid_cap, rounds=cfg.rounds_per_level,
         pack=use_pack, segmin=segmin_hook,
     )
-    return _analyze_lowered(lowered, "coarsen.level0")
+    return lowered, "coarsen.level0"
+
+
+def lower_plan(mode: str, target, rs):
+    """``(jax.stages.Lowered, name)`` of the dominant executable a flat
+    or coarsen plan over ``target`` runs under the resolved spec ``rs``:
+    the ``_msf_jit`` driver (flat), or the level-0 executable (coarsen;
+    the flat driver when no level runs). Lowered from shapes alone, with
+    the same statics the engine passes, so its compile is the one the
+    solve itself needs (and hits a warm persistent compile cache). Raises
+    on anything it cannot lower — callers that must not fail wrap it."""
+    if mode == "flat":
+        return _lower_flat(int(target.n), int(np.asarray(target.src).shape[0]), rs)
+    if mode == "coarsen":
+        return _lower_coarsen(target, rs)
+    raise ValueError(f"no dominant-executable lowering for mode {mode!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -209,12 +218,10 @@ def plan_cost(mode: str, target, rs) -> Optional[PlanCost]:
         with _lock:
             if key in _memo:
                 return _memo[key]
-        if mode == "flat":
-            cost = _flat_cost(
-                int(target.n), int(np.asarray(target.src).shape[0]), rs
-            )
-        else:
-            cost = _coarsen_cost(target, rs)
+        lowered, analyzed = lower_plan(mode, target, rs)
+        from repro.analysis.hlo_analyzer import analyze
+
+        cost = _from_analysis(analyze(lowered.compile().as_text()), analyzed)
         with _lock:
             _memo[key] = cost
         return cost

@@ -50,10 +50,10 @@ from typing import NamedTuple
 import numpy as np
 
 from repro import obs
-from repro.core.msf import flat_msf
+from repro.core.msf import run_flat
 from repro.core.semiring import PACK_IDX_MASK
 from repro.graphs.structures import Graph, edge_keys
-from repro.solve.spec import weights_packable
+from repro.solve.spec import resolve_flat_segmin, weights_packable
 from repro.stream import delta
 from repro.stream.service import next_pow2
 from repro.stream.snapshot import SnapshotStore, make_snapshot
@@ -816,17 +816,39 @@ class StreamEngine:
             r = eng(g)
             self.last_coarsen_stats = eng.last_stats
         else:
-            # flat_msf's backend resolution (repro.solve.spec) degrades
-            # "sorted" — a dedupe-only backend — to "auto" for the flat
-            # hook loop's unsorted segment ids.
             self.last_coarsen_stats = None
-            r = flat_msf(
-                g,
-                pack=use_pack,
-                segmin=self._segmin if use_pack else None,
-                **self._msf_opts,
-            )
+            r = run_flat(g, **self._flat_statics(use_pack))
         return r
+
+    def _flat_statics(self, use_pack: bool) -> dict:
+        """jit statics of the flat union solve. The backend resolution
+        (repro.solve.spec) degrades "sorted" — a dedupe-only backend — to
+        "auto" for the flat hook loop's unsorted segment ids."""
+        return dict(
+            pack=use_pack,
+            segmin=resolve_flat_segmin(self._segmin, use_pack),
+            **self._msf_opts,
+        )
+
+    def lower_union(self):
+        """``(jax.stages.Lowered, statics)`` of the flat union solve at
+        the current buffer shape and pack mode, lowered with the statics
+        the solve itself passes — so its compiled text is what an update
+        runs (on a TPU, a Pallas segment-min shows as ``tpu_custom_call``)."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.msf import _msf_jit
+
+        e = 2 * self.union_edge_capacity
+
+        def arr(dtype):
+            return jax.ShapeDtypeStruct((e,), dtype)
+
+        g = Graph(src=arr(jnp.int32), dst=arr(jnp.int32), w=arr(jnp.float32),
+                  eid=arr(jnp.int32), valid=arr(jnp.bool_), n=self.n)
+        statics = self._flat_statics(self._use_pack())
+        return _msf_jit.lower(g, **statics), statics
 
     @_spanned("stream.union_solve")
     def _run_union(self, b_lo, b_hi, b_w, b_gid):
