@@ -28,7 +28,7 @@ import sys
 import warnings
 
 from benchmarks.common import assert_msf_parity as _assert_parity
-from benchmarks.common import cost_fragment, emit, from_samples, measure, timeit, with_trace
+from benchmarks.common import cost_fragment, emit, from_samples, measure, timeit
 from repro.coarsen import CoarsenConfig
 from repro.graphs import grid_road_graph, rmat_graph
 from repro.solve import SolveSpec, plan
@@ -154,6 +154,6 @@ def run_rows(smoke: bool = False):
 if __name__ == "__main__":
     argv = sys.argv[1:]
     smoke = "--smoke" in argv
-    emit(with_trace(argv, lambda: run_rows(smoke=smoke)), argv)
+    emit(run_rows(smoke=smoke), argv)
     if smoke:
         print("# solve smoke: spec/deprecated path parity OK", file=sys.stderr)

@@ -242,21 +242,3 @@ def flag_value(argv: list[str], flag: str) -> str | None:
         raise SystemExit(f"{flag} requires an argument")
     return argv[at + 1]
 
-
-def with_trace(argv: list[str], fn):
-    """Run ``fn()`` under ``repro.obs`` trace mode when ``--trace PATH``
-    is present, exporting the Chrome-trace/Perfetto JSON to PATH after —
-    the shared bench-side surface of DESIGN.md §10.5. Without the flag,
-    ``fn()`` runs untouched (obs stays off)."""
-    path = flag_value(argv, "--trace")
-    if path is None:
-        return fn()
-    from repro import obs
-
-    obs.enable("trace")
-    try:
-        return fn()
-    finally:
-        obs.export_trace(path)
-        obs.disable()
-        obs.reset()

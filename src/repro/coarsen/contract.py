@@ -36,27 +36,6 @@ class ContractResult(NamedTuple):
     n_msf_edges: jax.Array  # int32 scalar
 
 
-def hook_rounds(reduce_fn, n: int, rounds: int):
-    """K hook+shortcut rounds from singleton stars, *without* the
-    rank/relabel tail: ``(parent, weight, msf_eids, n_msf_edges)``.
-
-    Split out of :func:`contract_rounds` so obs trace mode can run the
-    contraction and the relabel as separately-timed executables
-    (``repro.coarsen.engine`` DESIGN.md §10.3) — both paths compose the
-    identical pieces."""
-    p = jnp.arange(n, dtype=jnp.int32)
-    total = jnp.float32(0.0)
-    msf_eids = jnp.full((n,), IMAX, jnp.int32)
-    n_f = jnp.int32(0)
-    for _ in range(rounds):
-        r = reduce_fn(p)
-        p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
-        total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
-        msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
-        p = sc.complete_shortcut(p_h)
-    return p, total, msf_eids, n_f
-
-
 def contract_rounds(reduce_fn, n: int, rounds: int) -> ContractResult:
     """Shared K-round hook+shortcut driver; ``reduce_fn(p)`` yields the
     per-root MINWEIGHT EdgeMin for the current parent vector.
@@ -65,8 +44,19 @@ def contract_rounds(reduce_fn, n: int, rounds: int) -> ContractResult:
     same rounds inside ``shard_map`` with a cross-device reduce_fn — all
     the per-round bookkeeping (hook, tie-break, eid recording, shortcut,
     rank/relabel) operates on replicated dense vectors and is shared."""
-    p, total, msf_eids, n_f = hook_rounds(reduce_fn, n, rounds)
-    new_ids, n_next = rank_relabel(p)
+    with jax.named_scope("contract"):
+        p = jnp.arange(n, dtype=jnp.int32)
+        total = jnp.float32(0.0)
+        msf_eids = jnp.full((n,), IMAX, jnp.int32)
+        n_f = jnp.int32(0)
+        for _ in range(rounds):
+            r = reduce_fn(p)
+            p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
+            total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
+            msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
+            p = sc.complete_shortcut(p_h)
+    with jax.named_scope("relabel"):
+        new_ids, n_next = rank_relabel(p)
     return ContractResult(
         parent=p,
         new_ids=new_ids,
@@ -202,13 +192,14 @@ def make_und_reduce(
             # Mask weights BEFORE the uint32 cast (padding carries +inf).
             w_int = jnp.where(out, w, 0.0).astype(jnp.uint32)
             key = jnp.where(out, pack32(w_int, eid), PACK_IDENTITY)
-            if segmin is None:
-                m1 = jax.ops.segment_min(key, plo, num_segments=n)
-                m2 = jax.ops.segment_min(key, phi, num_segments=n)
-            else:
-                m1 = segmin(key, plo, n)
-                m2 = segmin(key, phi, n)
-            minkey = combine(jnp.minimum(m1, m2))
+            with jax.named_scope("segmin"):
+                if segmin is None:
+                    m1 = jax.ops.segment_min(key, plo, num_segments=n)
+                    m2 = jax.ops.segment_min(key, phi, num_segments=n)
+                else:
+                    m1 = segmin(key, plo, n)
+                    m2 = segmin(key, phi, n)
+                minkey = combine(jnp.minimum(m1, m2))
             w_out, eid_out = unpack32(minkey)
             empty = minkey == PACK_IDENTITY
             return EdgeMin(
@@ -221,20 +212,21 @@ def make_und_reduce(
             plo, phi = p[lo], p[hi]
             out = (plo != phi) & valid
             wm = jnp.where(out, w, INF)
-            minw = combine(jnp.minimum(
-                jax.ops.segment_min(wm, plo, num_segments=n),
-                jax.ops.segment_min(wm, phi, num_segments=n),
-            ))
-            on1 = out & (wm == minw[plo])
-            on2 = out & (wm == minw[phi])
-            mineid = combine(jnp.minimum(
-                jax.ops.segment_min(
-                    jnp.where(on1, eid, IMAX), plo, num_segments=n
-                ),
-                jax.ops.segment_min(
-                    jnp.where(on2, eid, IMAX), phi, num_segments=n
-                ),
-            ))
+            with jax.named_scope("segmin"):
+                minw = combine(jnp.minimum(
+                    jax.ops.segment_min(wm, plo, num_segments=n),
+                    jax.ops.segment_min(wm, phi, num_segments=n),
+                ))
+                on1 = out & (wm == minw[plo])
+                on2 = out & (wm == minw[phi])
+                mineid = combine(jnp.minimum(
+                    jax.ops.segment_min(
+                        jnp.where(on1, eid, IMAX), plo, num_segments=n
+                    ),
+                    jax.ops.segment_min(
+                        jnp.where(on2, eid, IMAX), phi, num_segments=n
+                    ),
+                ))
             empty = minw == INF
             return EdgeMin(
                 w=minw,

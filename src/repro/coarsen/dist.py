@@ -339,16 +339,17 @@ class DistCoarsenMSF:
                 *mesh_key, n_pad, eid_cap, cfg.rounds_per_level, use_pack,
                 segmin_hook, segmin_dedupe, in_mesh,
             )
-            with obs.span("dist.level", level=len(stats), n=n_cur,
-                          m=m_cur) as lsp:
-                out = lsp.attach(drv(lo, hi, w_b, eid_b, valid_b, label_map))
-            _account_allreduce(cfg.rounds_per_level, n_pad, use_pack)
-            n_next = int(out[7]) - (n_pad - n_cur)  # drop padding roots
-            if n_next == n_cur:  # every component already complete
-                break
-            n_f = int(out[10])
-            eids_acc.append(np.asarray(out[9][:n_f]))
-            weight += float(out[8])
+            # The span ends after the level's scalars and hooked eids are
+            # on the host: the first read waits for the level's program.
+            with obs.span("dist.level", level=len(stats), n=n_cur, m=m_cur):
+                out = drv(lo, hi, w_b, eid_b, valid_b, label_map)
+                _account_allreduce(cfg.rounds_per_level, n_pad, use_pack)
+                n_next = int(out[7]) - (n_pad - n_cur)  # drop padding roots
+                if n_next == n_cur:  # every component already complete
+                    break
+                n_f = int(out[10])
+                eids_acc.append(np.asarray(out[9][:n_f]))
+                weight += float(out[8])
             if in_mesh:
                 m_max, m_total = int(out[11]), int(out[12])
                 cap = _next_pow2(m_max)
@@ -377,12 +378,12 @@ class DistCoarsenMSF:
         rdrv = _residual_driver(
             *mesh_key, n_res_pad, eid_cap, use_pack, segmin_hook, limit
         )
-        with obs.span("dist.residual", n=n_cur, m=m_cur) as rsp:
-            p_res, r_weight, r_eids, r_nf, r_it = rsp.attach(
-                rdrv(lo, hi, w_b, eid_b, valid_b)
+        with obs.span("dist.residual", n=n_cur, m=m_cur):
+            p_res, r_weight, r_eids, r_nf, r_it = rdrv(
+                lo, hi, w_b, eid_b, valid_b
             )
-        # Residual rounds run the same per-round combine schedule.
-        _account_allreduce(int(r_it), n_res_pad, use_pack)
+            # Residual rounds run the same per-round combine schedule.
+            _account_allreduce(int(r_it), n_res_pad, use_pack)
 
         all_eids = np.concatenate(
             eids_acc + [np.asarray(r_eids[: int(r_nf)])]

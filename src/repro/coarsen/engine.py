@@ -33,19 +33,12 @@ import numpy as np
 
 from repro import obs
 from repro.coarsen.config import CoarsenConfig
-from repro.coarsen.contract import (
-    ContractResult,
-    contract_level,
-    contract_level_und,
-    hook_rounds,
-    make_und_reduce,
-)
+from repro.coarsen.contract import contract_level, contract_level_und
 from repro.coarsen.filter import (
     filter_level,
     filter_level_callback,
     filter_level_host,
 )
-from repro.coarsen.relabel import rank_relabel
 from repro.core.msf import MSFResult, flat_msf as _flat_msf
 from repro.graphs.partition import Partition2D, partition_edges_2d
 from repro.graphs.structures import Graph, graph_from_canonical
@@ -211,93 +204,17 @@ def fused_level(
     )
 
 
-@partial(
-    jax.jit,
-    static_argnames=("n", "eid_capacity", "rounds", "pack", "segmin"),
-)
-def _hook_rounds_und(
-    lo, hi, w, eid, valid, *, n, eid_capacity, rounds, pack, segmin=None
-):
-    """The contraction phase of :func:`contract_level_und` alone — K
-    hook+shortcut rounds, no relabel tail. Only the obs trace path uses
-    this; the production level keeps the single fused executable."""
-    reduce_fn = make_und_reduce(
-        lo, hi, w, eid, valid,
-        n=n, eid_capacity=eid_capacity, pack=pack, segmin=segmin,
-    )
-    return hook_rounds(reduce_fn, n, rounds)
-
-
-_rank_relabel_jit = jax.jit(rank_relabel)
-
-
-def _traced_contract(lo, hi, w, eid, valid, *, n, eid_capacity, rounds,
-                     pack, segmin) -> ContractResult:
-    """contract → relabel as two spanned, synced executables. Same
-    numbers as :func:`contract_level_und` (identical kernel composition);
-    the split exists so Perfetto shows the phases (DESIGN.md §10.3)."""
-    with obs.span("coarsen.contract", n=n, rounds=rounds) as sp:
-        p, weight, msf_eids, n_f = sp.attach(_hook_rounds_und(
-            lo, hi, w, eid, valid,
-            n=n, eid_capacity=eid_capacity, rounds=rounds, pack=pack,
-            segmin=segmin,
-        ))
-    with obs.span("coarsen.relabel", n=n) as sp:
-        new_ids, n_next = sp.attach(_rank_relabel_jit(p))
-    return ContractResult(
-        parent=p, new_ids=new_ids, n_next=n_next, weight=weight,
-        msf_eids=msf_eids, n_msf_edges=n_f,
-    )
-
-
-def _traced_fused_level(
-    lo, hi, w, eid, valid, label_map, *, n, eid_capacity, rounds, pack,
-    segmin, segmin_dedupe, dedupe_host,
-) -> FusedLevel:
-    """Trace-mode twin of :func:`fused_level`: the same level computation
-    as three separately-dispatched executables (contract, relabel,
-    filter), each under a device-synced span. Bit-identical outputs —
-    every phase is the same jitted piece the fused executable inlines —
-    at the cost of per-phase dispatch+sync; that asymmetry is the
-    documented profiler contract (obs="trace" measures phase costs,
-    obs="metrics"/"off" keep the one-jit production path)."""
-    res = _traced_contract(
-        lo, hi, w, eid, valid,
-        n=n, eid_capacity=eid_capacity, rounds=rounds, pack=pack,
-        segmin=segmin,
-    )
-    with obs.span("coarsen.filter", n=n, host=dedupe_host) as sp:
-        if dedupe_host:
-            fr = filter_level_callback(
-                lo, hi, w, eid, valid, res.new_ids, n=n
-            )
-        else:
-            fr = filter_level(
-                lo, hi, w, eid, valid, res.new_ids, n=n, pack=pack,
-                segmin=segmin_dedupe,
-            )
-        fr = sp.attach(fr)
-    return FusedLevel(
-        lo=fr.lo, hi=fr.hi, w=fr.w, eid=fr.eid, valid=fr.valid,
-        m_new=fr.m_new, new_ids=res.new_ids, n_next=res.n_next,
-        weight=res.weight, msf_eids=res.msf_eids,
-        n_msf_edges=res.n_msf_edges, label_map=res.new_ids[label_map],
-    )
-
-
 def _run_levels_fused(
-    graph: Graph, cfg: CoarsenConfig, use_pack: bool, canon
+    graph: Graph, cfg: CoarsenConfig, use_pack: bool, canon, eid_cap: int
 ) -> CoarsenPrelude:
     """Level loop over :func:`fused_level`: edge arrays and ``label_map``
     stay on device across levels; only per-level scalars (n_next, m_new)
-    and the hooked eids cross to the host for loop control/bookkeeping."""
+    and the hooked eids cross to the host for loop control/bookkeeping.
+    ``canon`` is the device copy of :func:`_canonical_host`'s arrays."""
     segmin_hook, segmin_dedupe = resolve_level_segmins(cfg.segmin, use_pack)
     dedupe = resolve_dedupe(cfg.dedupe)
     n0 = graph.n
-    lo_h, hi_h, w_h, eid_h, valid_h, m_cur = canon
-    eid_cap = _eid_capacity(eid_h, m_cur)
-    lo, hi = jnp.asarray(lo_h), jnp.asarray(hi_h)
-    w, eid, valid = jnp.asarray(w_h), jnp.asarray(eid_h), jnp.asarray(valid_h)
+    lo, hi, w, eid, valid, m_cur = canon
     label_map = jnp.arange(n0, dtype=jnp.int32)
 
     weight = 0.0
@@ -305,25 +222,24 @@ def _run_levels_fused(
     stats: list[LevelStats] = []
     n_cur = n0
 
-    traced = obs.trace_active()
     while len(stats) < cfg.max_levels and n_cur > cfg.cutoff and m_cur > 0:
         n_pad = next_pow2(n_cur, floor=8)
-        with obs.span("coarsen.level", level=len(stats), n=n_cur,
-                      m=m_cur) as lsp:
-            level_fn = _traced_fused_level if traced else fused_level
-            res = lsp.attach(level_fn(
+        # The span ends after the level's scalars and hooked eids are on
+        # the host: the first read waits for the level's executable.
+        with obs.span("coarsen.level", level=len(stats), n=n_cur, m=m_cur):
+            res = fused_level(
                 lo, hi, w, eid, valid, label_map,
                 n=n_pad, eid_capacity=eid_cap, rounds=cfg.rounds_per_level,
                 pack=use_pack, segmin=segmin_hook,
                 segmin_dedupe=segmin_dedupe, dedupe_host=dedupe == "host",
-            ))
-        n_next = int(res.n_next) - (n_pad - n_cur)  # drop padding roots
-        if n_next == n_cur:  # every component already complete
-            break
-        n_f = int(res.n_msf_edges)
-        eids_acc.append(np.asarray(res.msf_eids[:n_f]))
-        weight += float(res.weight)
-        m_next = int(res.m_new)
+            )
+            n_next = int(res.n_next) - (n_pad - n_cur)  # drop padding roots
+            if n_next == n_cur:  # every component already complete
+                break
+            n_f = int(res.n_msf_edges)
+            eids_acc.append(np.asarray(res.msf_eids[:n_f]))
+            weight += float(res.weight)
+            m_next = int(res.m_new)
         pad = _next_pow2(m_next)
         lo, hi, w, eid, valid = (
             res.lo[:pad], res.hi[:pad], res.w[:pad], res.eid[:pad],
@@ -337,12 +253,14 @@ def _run_levels_fused(
     residual = graph_from_canonical(
         lo, hi, w, eid, valid, next_pow2(n_cur, floor=8)
     )
+    with obs.span("solve.fetch"):
+        label_map = np.asarray(label_map)
     return CoarsenPrelude(
         weight=weight,
         msf_eids=(
             np.concatenate(eids_acc) if eids_acc else np.zeros(0, np.int32)
         ),
-        label_map=np.asarray(label_map),
+        label_map=label_map,
         residual=residual,
         stats=CoarsenStats(levels=tuple(stats), residual_n=n_cur,
                            residual_m=m_cur),
@@ -354,20 +272,25 @@ def run_levels(graph: Graph, config: CoarsenConfig | None = None) -> CoarsenPrel
     """Contract-and-filter until the cutoff; return the residual + prelude."""
     cfg = config or CoarsenConfig()
     n0 = graph.n
-    lo, hi, w, eid, valid, m_cur = _canonical_host(graph)
-    use_pack = (
-        auto_pack(np.asarray(graph.w), np.asarray(graph.eid),
-                  np.asarray(graph.valid), 2 * len(lo))
-        if cfg.pack is None
-        else cfg.pack
-    )
+    with obs.span("coarsen.canonical"):
+        lo, hi, w, eid, valid, m_cur = _canonical_host(graph)
+        use_pack = (
+            auto_pack(np.asarray(graph.w), np.asarray(graph.eid),
+                      np.asarray(graph.valid), 2 * len(lo))
+            if cfg.pack is None
+            else cfg.pack
+        )
+        eid_cap = _eid_capacity(eid, m_cur)
+        if cfg.fused:  # the levels keep their edge arrays on the device
+            lo, hi, w, eid, valid = (
+                jnp.asarray(a) for a in (lo, hi, w, eid, valid)
+            )
     if cfg.fused:
         return _run_levels_fused(
-            graph, cfg, use_pack, (lo, hi, w, eid, valid, m_cur)
+            graph, cfg, use_pack, (lo, hi, w, eid, valid, m_cur), eid_cap
         )
     segmin_fn, segmin_dedupe_fn = resolve_level_segmins(cfg.segmin, use_pack)
     dedupe = resolve_dedupe(cfg.dedupe)
-    eid_cap = _eid_capacity(eid, m_cur)
 
     label_map = np.arange(n0, dtype=np.int32)
     weight = 0.0
@@ -385,51 +308,41 @@ def run_levels(graph: Graph, config: CoarsenConfig | None = None) -> CoarsenPrel
         # supervertex ids remain contiguous in [0, R).
         n_pad = next_pow2(n_cur, floor=8)
         with obs.span("coarsen.level", level=len(stats), n=n_cur, m=m_cur):
-            if obs.trace_active():
-                res = _traced_contract(
-                    lo, hi, w, eid, valid,
-                    n=n_pad, eid_capacity=eid_cap,
-                    rounds=cfg.rounds_per_level, pack=use_pack,
-                    segmin=segmin_fn,
-                )
-            else:
-                res = contract_level_und(
-                    lo, hi, w, eid, valid,
-                    n=n_pad, eid_capacity=eid_cap,
-                    rounds=cfg.rounds_per_level,
-                    pack=use_pack, segmin=segmin_fn,
-                )
+            res = contract_level_und(
+                lo, hi, w, eid, valid,
+                n=n_pad, eid_capacity=eid_cap,
+                rounds=cfg.rounds_per_level,
+                pack=use_pack, segmin=segmin_fn,
+            )
             n_next = int(res.n_next) - (n_pad - n_cur)  # drop padding roots
             if n_next == n_cur:  # every component already complete
                 break
             n_f = int(res.n_msf_edges)
             eids_acc.append(np.asarray(res.msf_eids[:n_f]))
             weight += float(res.weight)
-            with obs.span("coarsen.filter", n=n_pad,
-                          host=dedupe == "host") as fsp:
-                if dedupe == "host":
-                    l2, h2, w2_, e2_ = filter_level_host(
-                        lo, hi, w, eid, valid, res.new_ids, n_cur
-                    )
-                    m_next = len(l2)
-                    pad = _next_pow2(m_next)
-                    lo = np.zeros(pad, np.int32)
-                    hi = np.zeros(pad, np.int32)
-                    w = np.full(pad, np.inf, np.float32)
-                    eid = np.full(pad, _IMAX, np.int32)
-                    lo[:m_next], hi[:m_next] = l2, h2
-                    w[:m_next], eid[:m_next] = w2_, e2_
-                else:
-                    fr = fsp.attach(filter_level(
-                        lo, hi, w, eid, valid, res.new_ids,
-                        n=n_pad, pack=use_pack, segmin=segmin_dedupe_fn,
-                    ))
-                    m_next = int(fr.m_new)
-                    pad = _next_pow2(m_next)
-                    lo = np.asarray(fr.lo[:pad])
-                    hi = np.asarray(fr.hi[:pad])
-                    w = np.asarray(fr.w[:pad])
-                    eid = np.asarray(fr.eid[:pad])
+            if dedupe == "host":
+                l2, h2, w2_, e2_ = filter_level_host(
+                    lo, hi, w, eid, valid, res.new_ids, n_cur
+                )
+                m_next = len(l2)
+                pad = _next_pow2(m_next)
+                lo = np.zeros(pad, np.int32)
+                hi = np.zeros(pad, np.int32)
+                w = np.full(pad, np.inf, np.float32)
+                eid = np.full(pad, _IMAX, np.int32)
+                lo[:m_next], hi[:m_next] = l2, h2
+                w[:m_next], eid[:m_next] = w2_, e2_
+            else:
+                fr = filter_level(
+                    lo, hi, w, eid, valid, res.new_ids,
+                    n=n_pad, pack=use_pack, segmin=segmin_dedupe_fn,
+                )
+                m_next = int(fr.m_new)
+                pad = _next_pow2(m_next)
+                lo = np.asarray(fr.lo[:pad])
+                hi = np.asarray(fr.hi[:pad])
+                w = np.asarray(fr.w[:pad])
+                eid = np.asarray(fr.eid[:pad])
             label_map = np.asarray(res.new_ids)[label_map]
             stats.append(LevelStats(n=n_cur, m=m_cur, n_next=n_next,
                                     m_next=m_next, hooked=n_f))
@@ -505,20 +418,20 @@ class CoarsenMSF:
         self.last_stats: CoarsenStats | None = None
 
     def __call__(self, graph: Graph) -> MSFResult:
-        with obs.span("coarsen.levels", n=graph.n):
-            prelude = run_levels(graph, self.config)
+        prelude = run_levels(graph, self.config)
         with obs.span("coarsen.residual", n=prelude.residual.n,
-                      m=prelude.stats.residual_m) as sp:
-            r = sp.attach(_flat_msf(prelude.residual, **self.msf_kw))
+                      m=prelude.stats.residual_m):
+            r = _flat_msf(prelude.residual, **self.msf_kw)
         self.last_stats = prelude.stats
-        return _finalize(
-            prelude,
-            np.asarray(r.parent),
-            np.asarray(r.msf_eids)[: int(r.n_msf_edges)],
-            float(r.weight),
-            int(r.iterations),
-            graph.n,
-        )
+        with obs.span("solve.fetch"):
+            parts = (
+                np.asarray(r.parent),
+                np.asarray(r.msf_eids)[: int(r.n_msf_edges)],
+                float(r.weight),
+                int(r.iterations),
+            )
+        with obs.span("coarsen.finalize"):
+            return _finalize(prelude, *parts, graph.n)
 
 
 def coarsen_msf(

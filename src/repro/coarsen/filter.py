@@ -82,6 +82,7 @@ def filter_level(
     )
 
 
+@jax.named_scope("filter")
 def filter_level_impl(
     und_lo: jax.Array,
     und_hi: jax.Array,
@@ -153,10 +154,11 @@ def filter_level_impl(
                 [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]]
             )
             seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1  # [0, E) ranks
-            if segmin is None:
-                minkey = jax.ops.segment_min(wkey_s, seg, num_segments=e)
-            else:
-                minkey = segmin(wkey_s, seg, e)
+            with jax.named_scope("segmin"):
+                if segmin is None:
+                    minkey = jax.ops.segment_min(wkey_s, seg, num_segments=e)
+                else:
+                    minkey = segmin(wkey_s, seg, e)
             seg_live = minkey != PACK_IDENTITY
             w_min, eid_min = unpack32(minkey)
             # Every member of a segment carries the identical pair key, so
@@ -175,10 +177,11 @@ def filter_level_impl(
                 ]
             )
             seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-            if segmin is None:
-                minkey = jax.ops.segment_min(wkey_s, seg, num_segments=e)
-            else:
-                minkey = segmin(wkey_s, seg, e)
+            with jax.named_scope("segmin"):
+                if segmin is None:
+                    minkey = jax.ops.segment_min(wkey_s, seg, num_segments=e)
+                else:
+                    minkey = segmin(wkey_s, seg, e)
             seg_live = minkey != PACK_IDENTITY
             w_min, eid_min = unpack32(minkey)
             lo_out = jnp.zeros((e,), jnp.int32).at[seg].set(lo_s)

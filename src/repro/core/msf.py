@@ -90,20 +90,25 @@ def record_edges(msf_eids, n_f, keep, r_eid):
 
 def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin):
     """One hook+shortcut round as ``body(state) -> state`` over the
-    6-tuple ``(p, total, msf_eids, n_f, it, done)``.
-
-    Shared by the jitted while_loop driver (:func:`_msf_jit`) and the
-    host-driven traced driver (:func:`_msf_traced`), so the two paths run
-    the *same* per-round computation — the obs parity contract (enabling
-    tracing never changes solver output) reduces to "one round is one
-    round" regardless of who owns the loop.
-    """
+    6-tuple ``(p, total, msf_eids, n_f, it, done)``: the body of
+    :func:`_msf_jit`'s while_loop."""
     n = graph.n
     src, dst, w, eid, valid = graph.src, graph.dst, graph.w, graph.eid, graph.valid
 
     def body_complete(state):
         p, total, msf_eids, n_f, it, _ = state
         p_prev = p
+        with jax.named_scope("hook"):
+            r = min_outgoing(p)
+            p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
+            total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
+            msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
+        with jax.named_scope("shortcut"):
+            p_next = shortcut_fn(p_h, p_prev)
+        done = jnp.all(p_next == p_prev)
+        return p_next, total, msf_eids, n_f, it + 1, done
+
+    def min_outgoing(p):
         if variant == "pairwise":
             # Paper §IV-A pairwise baseline: materialize m = (a_ij, p_j)
             # into an nnz-sized buffer (the extra writes), then reduce with
@@ -123,19 +128,12 @@ def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin):
             outgoing = (ps != m_pd) & valid
             from repro.core.semiring import segment_argmin
 
-            r = segment_argmin(m_w, m_eid, (m_pd,), ps, n, valid=outgoing)
-        elif pack:
-            r = min_outgoing_coo_packed(
+            return segment_argmin(m_w, m_eid, (m_pd,), ps, n, valid=outgoing)
+        if pack:
+            return min_outgoing_coo_packed(
                 p, src, dst, w, eid, valid, n, segmin=segmin
             )
-        else:
-            r = min_outgoing_coo(p, src, dst, w, eid, valid, n, segment="root")
-        p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
-        total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
-        msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
-        p_next = shortcut_fn(p_h, p_prev)
-        done = jnp.all(p_next == p_prev)
-        return p_next, total, msf_eids, n_f, it + 1, done
+        return min_outgoing_coo(p, src, dst, w, eid, valid, n, segment="root")
 
     def body_paper(state):
         p, total, msf_eids, n_f, it, _ = state
@@ -215,83 +213,21 @@ def _msf_jit(
     return MSFResult(weight=total, parent=p, msf_eids=msf_eids, n_msf_edges=n_f, iterations=it)
 
 
-@partial(
-    jax.jit,
-    static_argnames=("variant", "shortcut", "capacity", "pack", "segmin"),
-)
-def _msf_round(
-    graph: Graph,
-    state,
-    *,
-    variant: str,
-    shortcut: str,
-    capacity: int,
-    pack: bool,
-    segmin=None,
-):
-    """One hook+shortcut round as its own executable — the traced
-    driver's per-round step (the while_loop body, loop hoisted out)."""
-    shortcut_fn = sc.make_shortcut_fn(shortcut, capacity) if variant != "paper" else None
-    return _make_msf_body(graph, variant, shortcut_fn, pack, segmin)(state)
-
-
-def _msf_traced(
-    graph: Graph,
-    *,
-    parent0=None,
-    variant: str = "complete",
-    shortcut: str = "complete",
-    capacity: int = 1 << 16,
-    max_iters: int | None = None,
-    unroll_guard: bool = True,
-    pack: bool = False,
-    segmin=None,
-) -> MSFResult:
-    """Host-driven twin of :func:`_msf_jit` with one obs span per
-    hook/shortcut round (DESIGN.md §10.3).
-
-    A ``lax.while_loop`` hides the per-round timing from the host, so
-    trace mode moves the loop to Python: the same body
-    (:func:`_make_msf_body`) runs as one executable per round
-    (:func:`_msf_round`) with a ``msf.round`` span — device-synced via
-    ``attach`` — around each. Same rounds, same termination rule
-    (``done`` then the unroll guard), bit-identical result; the cost is
-    one dispatch + sync per round, which is exactly what a profiler is
-    allowed to spend.
-    """
-    from repro import obs
-
-    limit = _msf_limit(graph.n, max_iters)
-    state = _msf_init(graph, parent0)
-    with obs.span("msf.flat", n=graph.n, variant=variant) as sp:
-        while not bool(state[5]) and (
-            not unroll_guard or int(state[4]) < limit
-        ):
-            with obs.span("msf.round", round=int(state[4])) as rsp:
-                state = rsp.attach(_msf_round(
-                    graph, state,
-                    variant=variant, shortcut=shortcut, capacity=capacity,
-                    pack=pack, segmin=segmin,
-                ))
-        p = sp.attach(sc.complete_shortcut(state[0]))
-        sp.set(iterations=int(state[4]))
-    return MSFResult(
-        weight=state[1], parent=p, msf_eids=state[2],
-        n_msf_edges=state[3], iterations=state[4],
-    )
-
-
 def run_flat(graph: Graph, **kw) -> MSFResult:
-    """Flat-driver dispatch for callers holding a *resolved* segmin
+    """The jitted flat driver, for callers holding a *resolved* segmin
     callable (the ``repro.solve`` flat engine, :func:`flat_msf`, the
-    stream union solve):
-    the jitted while_loop driver normally, the span-per-round host
-    driver when obs trace mode is active."""
+    stream union solve), under an ``msf.flat`` span.
+
+    Every caller's first host read of the result is its edge count; it is
+    made here, inside the span, so the span ends when the solve has run
+    on the device (the value stays with the array, nothing is copied
+    twice)."""
     from repro import obs
 
-    if obs.trace_active():
-        return _msf_traced(graph, **kw)
-    return _msf_jit(graph, **kw)
+    with obs.span("msf.flat"):
+        r = _msf_jit(graph, **kw)
+        int(r.n_msf_edges)
+    return r
 
 
 def flat_msf(graph: Graph, *, pack: bool = False, segmin: str | None = None,

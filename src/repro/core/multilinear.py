@@ -77,6 +77,7 @@ def project_to_roots(q: EdgeMin, p: jax.Array, n: int) -> EdgeMin:
     return segment_argmin(q.w, q.eid, q.payload, p, n, valid=q.w < INF)
 
 
+@jax.named_scope("segmin")
 def min_outgoing_coo_packed(
     p: jax.Array,
     src: jax.Array,

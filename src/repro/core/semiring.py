@@ -40,6 +40,7 @@ class EdgeMin(NamedTuple):
     payload: Tuple[jax.Array, ...]  # int32 [n] each; IMAX where empty
 
 
+@jax.named_scope("segmin")
 def segment_argmin(
     w: jax.Array,
     eid: jax.Array,

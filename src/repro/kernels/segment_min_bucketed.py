@@ -160,6 +160,7 @@ def segment_min_bucketed_pallas(
     sub, rsub = be_pad // LANES, block_rows // LANES
     out = pl.pallas_call(
         _bucket_kernel,
+        name="segment_min_bucketed",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((None, sub, LANES), lambda b: (b, 0, 0)),
@@ -250,6 +251,7 @@ def segment_min_flat_pallas(
     br, be = check_flat_layout(e, num_segments, block_rows, block_edges)
     out = pl.pallas_call(
         _flat_kernel,
+        name="segment_min_flat",
         grid=(num_segments // br, e // be),
         in_specs=[
             pl.BlockSpec((be // LANES, LANES), lambda rb, eb: (eb, 0)),

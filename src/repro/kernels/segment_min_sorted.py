@@ -159,6 +159,7 @@ def segment_min_sorted_pallas(
     )
     out = pl.pallas_call(
         _sorted_kernel,
+        name="segment_min_sorted",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_segments // LANES, LANES), jnp.int32),
         interpret=interpret,

@@ -173,8 +173,10 @@ def main(argv=None):
     ap.add_argument("--delete-every", type=int, default=0,
                     help="if >0, tombstone a small batch after every k-th insert")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--trace", metavar="PATH", default=None,
-                    help="export a Chrome-trace/Perfetto JSON of the run")
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="record the replay in a jax.profiler trace under "
+                         "DIR (TensorBoard / xprof; the repro.obs spans "
+                         "sit on the device trace's clock)")
     ap.add_argument("--metrics-every", type=int, default=0, metavar="K",
                     help="if >0, dump the obs metrics snapshot (incl. "
                          "query-latency p50/p95/p99) every K batches")
@@ -188,9 +190,7 @@ def main(argv=None):
     from repro.graphs.structures import from_edges
     from repro.solve import SolveSpec, plan
 
-    if args.trace:
-        obs.enable("trace")
-    elif args.metrics_every:
+    if args.metrics_every:
         obs.enable("metrics")
 
     n = 1 << args.scale
@@ -207,6 +207,10 @@ def main(argv=None):
         f"union_buffer={2 * engine.union_edge_capacity} directed slots"
     )
 
+    if args.trace:
+        import jax
+
+        jax.profiler.start_trace(args.trace)
     up_lat, q_tp = [], []
     for k in range(n_batches):
         sl = slice(k * args.batch_size, (k + 1) * args.batch_size)
@@ -251,9 +255,8 @@ def main(argv=None):
     print(f"queries: median {np.median(q_tp) / 1e6:.2f}M/s "
           f"(batch={args.queries_per_batch})")
     if args.trace:
-        obs.export_trace(args.trace)
-        print(f"# trace written to {args.trace} "
-              f"({len(obs.trace_events())} spans) — open in ui.perfetto.dev")
+        jax.profiler.stop_trace()
+        print(f"# profiler trace written under {args.trace}")
 
     if not args.delete_every:
         full = plan(

@@ -1,18 +1,18 @@
-# Observability substrate (DESIGN.md §10): span tracing with Chrome-
-# trace/Perfetto export (repro.obs.trace) + a process-global metrics
-# registry of counters / gauges / fixed-bucket latency histograms
+# Observability substrate (DESIGN.md §10): spans written into the JAX
+# profiler's trace (repro.obs.trace) + a process-global metrics registry
+# of counters / gauges / fixed-bucket latency histograms
 # (repro.obs.metrics). Leaf package — imported by every layer (core,
-# coarsen, stream, solve, launch, benchmarks), so it must not import any
-# of them; jax is only touched lazily at span exit (block_until_ready).
+# coarsen, stream, solve, serve, launch, benchmarks), so it must not
+# import any of them.
 #
 #     from repro import obs
-#     obs.enable("trace")
-#     with obs.span("solve", n=graph.n) as sp:
-#         sp.attach(run(graph))
-#     obs.export_trace("trace.json")
+#     with obs.span("solve", n=graph.n):
+#         report = run(graph)
 #
-# The declarative route is `SolveSpec(obs="trace")` — the plan layer
-# scopes the mode around each solve and fills `SolveReport.timings`.
+# A span costs one TraceMe activity check while no profiler records; a
+# `jax.profiler.trace(dir)` session records every span on the device
+# trace's clock. `obs.enable("metrics")` (or `SolveSpec(obs="metrics")`,
+# scoped per solve by the plan layer) also feeds `span.<name>` histograms.
 from repro.obs.metrics import (
     DEFAULT_LATENCY_BUCKETS,
     DEFAULT_REGISTRY,
@@ -25,36 +25,24 @@ from repro.obs.metrics import (
 from repro.obs.trace import (
     MODES,
     NOOP_SPAN,
-    collect_timings,
     disable,
     enable,
     enabled,
-    export_trace,
     metrics_active,
     mode,
-    reset,
     span,
-    sync_active,
-    trace_active,
-    trace_events,
 )
 
 __all__ = [
     # tracing
     "MODES",
     "NOOP_SPAN",
-    "collect_timings",
     "disable",
     "enable",
     "enabled",
-    "export_trace",
     "metrics_active",
     "mode",
-    "reset",
     "span",
-    "sync_active",
-    "trace_active",
-    "trace_events",
     # metrics
     "DEFAULT_LATENCY_BUCKETS",
     "DEFAULT_REGISTRY",

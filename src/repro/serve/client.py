@@ -30,6 +30,7 @@ import threading
 from concurrent.futures import Future
 from typing import Dict, Optional, Sequence
 
+from repro import obs
 from repro.serve import protocol as P
 
 
@@ -84,10 +85,12 @@ class ServeClient:
                 data = self._sock.recv(1 << 16)
                 if not data:
                     break
-                for item in decoder.feed(data):
-                    if isinstance(item, P.ProtocolError):
-                        continue  # server never sends malformed frames
-                    self._resolve(item)
+                # decoding and the futures' callbacks; not the wait above
+                with obs.span("client.recv"):
+                    for item in decoder.feed(data):
+                        if isinstance(item, P.ProtocolError):
+                            continue  # server never sends malformed frames
+                        self._resolve(item)
         except (OSError, P.ProtocolError):
             pass
         finally:
@@ -115,19 +118,20 @@ class ServeClient:
         """Pipeline one request; the Future resolves to the response dict."""
         if self._closed:
             raise ConnectionError("client is closed")
-        req_id = next(self._ids)
-        req = {"schema": P.SCHEMA, "id": req_id, "op": op, **fields}
-        frame = P.encode_frame(req)
-        fut: Future = Future()
-        with self._pending_lock:
-            self._pending[req_id] = fut
-        try:
-            with self._send_lock:
-                self._sock.sendall(frame)
-        except OSError:
+        with obs.span("client.send"):
+            req_id = next(self._ids)
+            req = {"schema": P.SCHEMA, "id": req_id, "op": op, **fields}
+            frame = P.encode_frame(req)
+            fut: Future = Future()
             with self._pending_lock:
-                self._pending.pop(req_id, None)
-            raise
+                self._pending[req_id] = fut
+            try:
+                with self._send_lock:
+                    self._sock.sendall(frame)
+            except OSError:
+                with self._pending_lock:
+                    self._pending.pop(req_id, None)
+                raise
         return fut
 
     def call(self, op: str, *, check: bool = False,

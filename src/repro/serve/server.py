@@ -39,7 +39,10 @@ Obs surface (metrics mode is enabled at server start): counters
 ``serve.requests`` / ``serve.queries`` / ``serve.writes`` /
 ``serve.errors.<code>``, gauge ``serve.queue_depth``, histograms
 ``serve.e2e_latency_s`` (admission → host-resident answer) and
-``serve.batch_occupancy`` (fused points per flush).
+``serve.batch_occupancy`` (fused points per flush). Host spans on the
+event loop, each a synchronous section between two awaits:
+``serve.decode`` (frame decoding and query admission), ``serve.flush``
+and ``serve.respond``.
 """
 from __future__ import annotations
 
@@ -88,33 +91,49 @@ class ServeConfig:
 
 
 class _Conn:
-    """Per-connection send side: a writer + an asyncio lock so batcher,
-    writer task and the reader's own error responses never interleave
-    partial frames on one socket."""
+    """Per-connection send side. Frames are queued on the transport whole,
+    with no ``await`` in between, so batcher, writer task and the
+    reader's own error responses never interleave partial frames on one
+    socket; an asyncio lock orders the drains."""
 
     def __init__(self, writer: asyncio.StreamWriter):
         self.writer = writer
         self.lock = asyncio.Lock()
         self.open = True
 
-    async def send(self, obj: dict, *, max_payload: int) -> None:
+    def write(self, obj: dict, *, max_payload: int) -> bool:
+        """Frame ``obj`` and queue it on the transport; False when nothing
+        was written (closed connection, or a response too large to
+        frame)."""
         if not self.open:
-            return
+            return False
         try:
             frame = P.encode_frame(obj, max_payload=max_payload)
         except P.ProtocolError:
             # a response we cannot frame (pathological batch): drop it —
             # the client's timeout handles the rest
             obs.counter("serve.errors.response_too_large").inc()
-            return
+            return False
+        try:
+            self.writer.write(frame)
+        except (ConnectionError, RuntimeError):
+            self.open = False
+            return False
+        return True
+
+    async def drain(self) -> None:
+        """Wait while the transport holds more than its high-water mark."""
         async with self.lock:
             if not self.open:
                 return
             try:
-                self.writer.write(frame)
                 await self.writer.drain()
             except (ConnectionError, RuntimeError):
                 self.open = False
+
+    async def send(self, obj: dict, *, max_payload: int) -> None:
+        if self.write(obj, max_payload=max_payload):
+            await self.drain()
 
 
 class _PendingQuery(NamedTuple):
@@ -256,7 +275,8 @@ class MSFServer:
                 if not data:
                     break
                 try:
-                    items = decoder.feed(data)
+                    with obs.span("serve.decode"):
+                        items = decoder.feed(data)
                 except P.ProtocolError as e:
                     # unrecoverable framing violation: answer, then close
                     obs.counter(f"serve.errors.{e.code}").inc()
@@ -323,24 +343,24 @@ class MSFServer:
 
     async def _admit_query(self, conn: _Conn, req_id, op: str,
                            fields: dict) -> None:
+        with obs.span("serve.decode"):
+            refused = self._enqueue_query(conn, req_id, op, fields)
+        if refused is not None:
+            await self._error(conn, req_id, op, *refused)
+
+    def _enqueue_query(self, conn: _Conn, req_id, op: str, fields: dict):
+        """Admit one query request; ``(code, message)`` when refused."""
         u = np.asarray(fields["u"], np.int64)
         v = np.asarray(fields.get("v", fields["u"]), np.int64)
         k = len(u)
         if k == 0 or k > self.service.max_batch:
-            await self._error(
-                conn, req_id, op, "bad_request",
-                f"query batch must have 1..{self.service.max_batch} points",
-            )
-            return
+            return ("bad_request",
+                    f"query batch must have 1..{self.service.max_batch} points")
         n = self._engine.n
         if u.min() < 0 or v.min() < 0 or max(u.max(), v.max()) >= n:
-            await self._error(conn, req_id, op, "bad_request",
-                              f"query vertex out of range [0, {n})")
-            return
+            return "bad_request", f"query vertex out of range [0, {n})"
         if self._admitted_points + k > self.config.queue_cap:
-            await self._error(conn, req_id, op, "overloaded",
-                              "admission queue full; retry with backoff")
-            return
+            return "overloaded", "admission queue full; retry with backoff"
         now = time.monotonic()
         deadline_ms = fields.get("deadline_ms", self.config.deadline_ms)
         self._admission.append(_PendingQuery(
@@ -350,10 +370,14 @@ class MSFServer:
         self._admitted_points += k
         obs.gauge("serve.queue_depth").set(self._admitted_points)
         self._admit_event.set()
+        return None
 
     async def _batch_loop(self) -> None:
-        """Micro-batched admission: one fused padded batch per tick."""
-        cfg = self.config
+        """Micro-batched admission: one fused padded batch per tick. The
+        loop's own work is spanned between its awaits: ``serve.flush``
+        (batch assembly) and ``serve.respond`` (result lists, framing,
+        writes); the fused call is ``stream.query`` on the query
+        thread."""
         loop = asyncio.get_running_loop()
         while True:
             await self._admit_event.wait()
@@ -361,61 +385,79 @@ class MSFServer:
             # let same-tick arrivals join this flush before assembling
             await asyncio.sleep(0)
             while self._admission:
-                batch: list[_PendingQuery] = []
-                points = 0
-                now = time.monotonic()
-                while self._admission and points < cfg.micro_batch:
-                    q = self._admission.popleft()
-                    self._admitted_points -= len(q.u)
-                    if now > q.deadline:
-                        await self._error(
-                            q.conn, q.req_id, q.op, "deadline",
-                            "query deadline expired in the admission queue",
-                        )
-                        continue
-                    batch.append(q)
-                    points += len(q.u)
-                obs.gauge("serve.queue_depth").set(self._admitted_points)
+                with obs.span("serve.flush"):
+                    batch, expired, u, v = self._take_batch()
+                for q in expired:
+                    await self._error(
+                        q.conn, q.req_id, q.op, "deadline",
+                        "query deadline expired in the admission queue",
+                    )
                 if not batch:
                     continue
-                u = np.concatenate([q.u for q in batch])
-                v = np.concatenate([q.v for q in batch])
-                obs.histogram(
-                    "serve.batch_occupancy", _OCCUPANCY_BOUNDS
-                ).observe(float(len(u)))
                 # the fused device call off the loop: admission continues
                 ans = await loop.run_in_executor(
                     self._query_pool, self.service.answer, u, v
                 )
                 t_done = time.monotonic()
-                hist = obs.histogram("serve.e2e_latency_s")
-                snap = ans.snapshot
-                at = 0
-                for q in batch:
-                    k = len(q.u)
-                    sl = slice(at, at + k)
-                    at += k
-                    if q.op == "connected":
-                        result = {
-                            "connected": [bool(x) for x in ans.connected[sl]]
-                        }
-                    elif q.op == "component_id":
-                        result = {
-                            "component": [int(x) for x in ans.component[sl]]
-                        }
-                    else:
-                        result = {"size": [int(x) for x in ans.size[sl]]}
-                    self._served_queries += k
-                    obs.counter("serve.queries").inc(k)
-                    hist.observe(t_done - q.t_admit)
-                    await q.conn.send(
-                        P.response(
-                            q.req_id, q.op, result,
-                            snapshot_version=snap.version, stale=snap.stale,
-                            n_unhealed=snap.n_unhealed,
-                        ),
-                        max_payload=cfg.max_payload,
-                    )
+                with obs.span("serve.respond"):
+                    conns = self._respond(batch, ans, t_done)
+                for conn in conns:
+                    await conn.drain()
+
+    def _take_batch(self):
+        """Pop up to ``micro_batch`` points of admitted queries: (the
+        batch, the queries whose deadline passed in the queue, the
+        batch's concatenated endpoints)."""
+        batch: list[_PendingQuery] = []
+        expired: list[_PendingQuery] = []
+        points = 0
+        now = time.monotonic()
+        while self._admission and points < self.config.micro_batch:
+            q = self._admission.popleft()
+            self._admitted_points -= len(q.u)
+            if now > q.deadline:
+                expired.append(q)
+                continue
+            batch.append(q)
+            points += len(q.u)
+        obs.gauge("serve.queue_depth").set(self._admitted_points)
+        if not batch:
+            return batch, expired, None, None
+        u = np.concatenate([q.u for q in batch])
+        v = np.concatenate([q.v for q in batch])
+        obs.histogram(
+            "serve.batch_occupancy", _OCCUPANCY_BOUNDS
+        ).observe(float(len(u)))
+        return batch, expired, u, v
+
+    def _respond(self, batch, ans, t_done: float) -> set:
+        """Write every response of one answered batch, all pinned to its
+        snapshot; returns the connections to drain."""
+        hist = obs.histogram("serve.e2e_latency_s")
+        snap = ans.snapshot
+        conns = set()
+        at = 0
+        for q in batch:
+            k = len(q.u)
+            sl = slice(at, at + k)
+            at += k
+            if q.op == "connected":
+                result = {"connected": [bool(x) for x in ans.connected[sl]]}
+            elif q.op == "component_id":
+                result = {"component": [int(x) for x in ans.component[sl]]}
+            else:
+                result = {"size": [int(x) for x in ans.size[sl]]}
+            self._served_queries += k
+            obs.counter("serve.queries").inc(k)
+            hist.observe(t_done - q.t_admit)
+            resp = P.response(
+                q.req_id, q.op, result,
+                snapshot_version=snap.version, stale=snap.stale,
+                n_unhealed=snap.n_unhealed,
+            )
+            if q.conn.write(resp, max_payload=self.config.max_payload):
+                conns.add(q.conn)
+        return conns
 
     # -- write lane --------------------------------------------------------
 

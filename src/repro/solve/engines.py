@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.solve.planner import register_engine
 from repro.solve.report import SolveReport, report_from_msf_result
 from repro.solve.spec import ResolvedSpec
@@ -42,7 +43,8 @@ class _FlatEngine:
             pack=bool(rs.pack),
             segmin=rs.segmin_flat,
         )
-        return report_from_msf_result("flat", r)
+        with obs.span("solve.fetch"):  # the forest's copy to the host
+            return report_from_msf_result("flat", r)
 
 
 def _build_flat(target, rs: ResolvedSpec, mesh):

@@ -173,12 +173,13 @@ def plan(target, spec: SolveSpec | None = None, *, mesh=None, **overrides) -> "P
             # drivers (dist mode traces the whole shard_map program here).
             with obs.span("plan.build", mode=spec.mode):
                 engine = edef.builder(target, resolved, mesh)
-                # Analytic cost of the executable this engine runs
-                # (flat / coarsen scope; best-effort). Stored on the
-                # engine so cache hits reuse the analysis with the
-                # compiled machinery.
-                from repro.solve.cost import plan_cost
+            # Analytic cost of the executable this engine runs (flat /
+            # coarsen scope; best-effort): it lowers and compiles that
+            # executable. Stored on the engine so cache hits reuse the
+            # analysis with the compiled machinery.
+            from repro.solve.cost import plan_cost
 
+            with obs.span("plan.cost", mode=spec.mode):
                 engine._plan_cost = plan_cost(spec.mode, target, resolved)
             if key is not None:
                 _cache_put(key, engine)
@@ -248,19 +249,11 @@ class Plan:
         return rep
 
     def _observed(self, what: str, call):
-        """Run one engine call under this spec's ``obs`` scope: a
-        ``solve.<mode>[.<what>]`` span, and — for SolveReport-shaped
-        results — the per-phase ``timings`` aggregation. The fully-off
-        path (global mode off, spec knob off) is two attribute checks
-        plus the zero-work cost attach (a NamedTuple ``_replace``)."""
-        if not obs.metrics_active() and self.spec.obs == "off":
-            return self._attach_cost(call())
+        """Run one engine call under a ``solve.<mode>[.<what>]`` span and
+        this spec's ``obs`` scope, then attach the plan's analytic cost."""
         name = f"solve.{self.spec.mode}" + (f".{what}" if what else "")
-        with obs.enabled(self.spec.obs):
-            with obs.collect_timings() as t, obs.span(name):
-                rep = call()
-            if t and isinstance(rep, SolveReport):
-                rep = rep._replace(timings=dict(t))
+        with obs.enabled(self.spec.obs), obs.span(name):
+            rep = call()
         return self._attach_cost(rep)
 
     def solve(self, *args, **kw) -> SolveReport:

@@ -6,15 +6,14 @@ The deprecated entry points each reported a different type
 the union of what callers actually consume — forest weight, the chosen
 global eids, component labels, iteration count, the per-level coarsening
 rows, the two operational counters (host round-trips, recompiles), and
-the per-phase wall-clock breakdown (``timings``, filled when the spec's
-``obs`` knob is on — DESIGN.md §10), and the analytic ``cost`` of the
-plan's executable (``repro.solve.cost.PlanCost``, computed once at
-``plan.build`` — DESIGN.md §11) — plus the engine-native result under
-``raw`` for anything mode-specific.
+the analytic ``cost`` of the plan's executable
+(``repro.solve.cost.PlanCost``, computed once at ``plan.cost`` —
+DESIGN.md §11) — plus the engine-native result under ``raw`` for
+anything mode-specific.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import numpy as np
 
@@ -32,7 +31,6 @@ class SolveReport(NamedTuple):
     host_roundtrips: int  # per-level host round-trips (0 = device-resident)
     recompiles: int  # distinct executables compiled (stream mode)
     raw: Any  # engine-native result (MSFResult / UpdateStats / ...)
-    timings: Dict[str, float] = {}  # span name -> seconds; {} when obs off
     cost: Any = None  # PlanCost of the plan's executable; None off-scope
     stale: bool = False  # stream mode: snapshot may diverge from true MSF
     n_unhealed: int = 0  # stream mode: deletions not certifiably healed

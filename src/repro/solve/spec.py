@@ -41,12 +41,11 @@ import numpy as np
 from repro.core.semiring import PACK_IDX_MASK
 
 MODES = ("flat", "coarsen", "dist", "stream")
-#: Observability levels of the ``obs`` knob (DESIGN.md §10): "off" = the
-#: one-branch no-op path, "metrics" = span-duration histograms + counters
-#: in the process-global registry, "trace" = additionally record Chrome-
-#: trace events (and take per-phase device-sync'd code paths where a
-#: fused executable would otherwise hide the phases).
-OBS_MODES = ("off", "metrics", "trace")
+#: Observability levels of the ``obs`` knob (DESIGN.md §10): "off" = spans
+#: only reach a recording profiler, "metrics" = span-duration histograms
+#: + counters in the process-global registry too. Either mode runs the
+#: same executables; a ``jax.profiler`` session is what records a trace.
+OBS_MODES = ("off", "metrics")
 #: Tuning-database consultation levels of the ``tuning`` knob
 #: (DESIGN.md §12): "off" = the hand-written heuristics below, "db" =
 #: consult the active ``tuning-db/v1`` database first (exact key, then
@@ -193,9 +192,8 @@ class SolveSpec:
     # dist mode
     row_axis: str = "data"
     col_axis: str = "model"
-    # observability: "off" | "metrics" | "trace" (DESIGN.md §10). Scoped
-    # around every Plan.solve()/update()/query() of this spec; "trace"
-    # also fills SolveReport.timings and the exportable trace buffer.
+    # observability: "off" | "metrics" (DESIGN.md §10). Scoped around
+    # every Plan.solve()/update()/query() of this spec.
     obs: str = "off"
     # tuning-database consultation: "off" | "db" | "measure"
     # (DESIGN.md §12, ``repro.solve.tune``).

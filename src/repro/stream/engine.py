@@ -561,69 +561,68 @@ class StreamEngine:
         reset (modulo refill evictions), ``unhealed`` drops to 0 and the
         published snapshot is exact (``stale=False``).
         """
-        pb = delta.prepare_batch(u, v, w, self.n)
-        # Thread stable gids through by canonical pair key.
-        live = np.flatnonzero(~self._dead[: self._count])
-        r_lo, r_hi, _, r_gid, _ = self._reservoir.edges()
-        known_keys = np.concatenate(
-            [
-                edge_keys(self._lo[live], self._hi[live], self.n),
-                edge_keys(r_lo, r_hi, self.n),
-            ]
-        )
-        known_gids = np.concatenate([self._gid[live], r_gid])
-        order = np.argsort(known_keys, kind="stable")
-        known_keys, known_gids = known_keys[order], known_gids[order]
-        kq = edge_keys(pb.lo, pb.hi, self.n)
-        gid = np.empty(pb.count, np.int32)
-        match = np.zeros(pb.count, bool)
-        if len(known_keys) and pb.count:
-            j = np.clip(np.searchsorted(known_keys, kq), 0, len(known_keys) - 1)
-            match = known_keys[j] == kq
-            gid[match] = known_gids[j[match]]
-        n_fresh = int((~match).sum())
-        gid[~match] = np.arange(
-            self._next_gid, self._next_gid + n_fresh, dtype=np.int32
-        )
-        self._next_gid += n_fresh
-        # The supplied multiset replaces the engine's history, so
-        # packability restarts from it instead of the running conjunction.
-        ok = weights_packable(pb.w)
-        if not ok and self._pack is True:
-            raise ValueError(
-                "pack=True requires integral weights in [0, 255]; "
-                "construct with pack=None/False for general weights"
+        # the host phase: gids threaded through, the padded buffer built
+        with obs.span("stream.recertify.prepare"):
+            pb = delta.prepare_batch(u, v, w, self.n)
+            # Thread stable gids through by canonical pair key.
+            live = np.flatnonzero(~self._dead[: self._count])
+            r_lo, r_hi, _, r_gid, _ = self._reservoir.edges()
+            known_keys = np.concatenate(
+                [
+                    edge_keys(self._lo[live], self._hi[live], self.n),
+                    edge_keys(r_lo, r_hi, self.n),
+                ]
             )
-        self._packable = ok
-        cap = next_pow2(max(pb.count, 1), 1)
-        use_pack = (
-            self._pack
-            if self._pack is not None
-            else self._packable and cap < PACK_IDX_MASK
-        )
-        if use_pack and cap >= PACK_IDX_MASK:
-            raise ValueError(
-                f"pack=True needs local eids < 2^24 - 1; recertify over "
-                f"{pb.count} edges overflows the pack32 index field"
+            known_gids = np.concatenate([self._gid[live], r_gid])
+            order = np.argsort(known_keys, kind="stable")
+            known_keys, known_gids = known_keys[order], known_gids[order]
+            kq = edge_keys(pb.lo, pb.hi, self.n)
+            gid = np.empty(pb.count, np.int32)
+            match = np.zeros(pb.count, bool)
+            if len(known_keys) and pb.count:
+                j = np.clip(np.searchsorted(known_keys, kq), 0, len(known_keys) - 1)
+                match = known_keys[j] == kq
+                gid[match] = known_gids[j[match]]
+            n_fresh = int((~match).sum())
+            gid[~match] = np.arange(
+                self._next_gid, self._next_gid + n_fresh, dtype=np.int32
             )
-        lo_u = np.zeros(cap, np.int32)
-        hi_u = np.zeros(cap, np.int32)
-        w_u = np.full(cap, np.inf, np.float32)
-        gid_u = np.full(cap, -1, np.int32)
-        valid_u = np.zeros(cap, bool)
-        # gid-ordered slots, as in _run_union: ties resolve to the
-        # strict (w, gid) order, so the rebuilt forest is the same one
-        # incremental maintenance over this multiset would have produced
-        order = np.argsort(gid, kind="stable")
-        lo_u[: pb.count], hi_u[: pb.count] = pb.lo[order], pb.hi[order]
-        w_u[: pb.count], gid_u[: pb.count] = pb.w[order], gid[order]
-        valid_u[: pb.count] = True
-        g = self._union_graph(lo_u, hi_u, w_u, valid_u)
-        self._union_shapes.add((tuple(g.src.shape), bool(use_pack)))
-        self.last_union_shape = tuple(g.src.shape)
-        r = self._solve_graph(g, pb.count, bool(use_pack))
-        self._unhealed = 0
-        self._commit(r, lo_u, hi_u, w_u, gid_u, valid_u, reset_reservoir=True)
+            self._next_gid += n_fresh
+            # The supplied multiset replaces the engine's history, so
+            # packability restarts from it instead of the running conjunction.
+            ok = weights_packable(pb.w)
+            if not ok and self._pack is True:
+                raise ValueError(
+                    "pack=True requires integral weights in [0, 255]; "
+                    "construct with pack=None/False for general weights"
+                )
+            self._packable = ok
+            cap = next_pow2(max(pb.count, 1), 1)
+            use_pack = (
+                self._pack
+                if self._pack is not None
+                else self._packable and cap < PACK_IDX_MASK
+            )
+            if use_pack and cap >= PACK_IDX_MASK:
+                raise ValueError(
+                    f"pack=True needs local eids < 2^24 - 1; recertify over "
+                    f"{pb.count} edges overflows the pack32 index field"
+                )
+            lo_u = np.zeros(cap, np.int32)
+            hi_u = np.zeros(cap, np.int32)
+            w_u = np.full(cap, np.inf, np.float32)
+            gid_u = np.full(cap, -1, np.int32)
+            valid_u = np.zeros(cap, bool)
+            # gid-ordered slots, as in _run_union: ties resolve to the
+            # strict (w, gid) order, so the rebuilt forest is the same one
+            # incremental maintenance over this multiset would have produced
+            order = np.argsort(gid, kind="stable")
+            lo_u[: pb.count], hi_u[: pb.count] = pb.lo[order], pb.hi[order]
+            w_u[: pb.count], gid_u[: pb.count] = pb.w[order], gid[order]
+            valid_u[: pb.count] = True
+            g = self._union_graph(lo_u, hi_u, w_u, valid_u)
+        r = self._solve_and_commit(g, (lo_u, hi_u, w_u, gid_u, valid_u),
+                                   pb.count, use_pack, reset_reservoir=True)
         return UpdateStats(
             version=self._version,
             weight=self._weight,
@@ -850,10 +849,18 @@ class StreamEngine:
         statics = self._flat_statics(self._use_pack())
         return _msf_jit.lower(g, **statics), statics
 
-    @_spanned("stream.union_solve")
     def _run_union(self, b_lo, b_hi, b_w, b_gid):
         """MSF over (live forest ∪ batch) in the fixed-capacity union
-        buffer; rewrite the store from the result and publish a snapshot."""
+        buffer; rewrite the store from the result and publish a snapshot.
+        Host spans: ``stream.union.prepare`` (the buffer), then
+        :meth:`_solve_and_commit`'s solve and publish."""
+        with obs.span("stream.union.prepare"):
+            g, bufs, m, use_pack = self._union_buffer(b_lo, b_hi, b_w, b_gid)
+        return self._solve_and_commit(g, bufs, m, use_pack)
+
+    def _union_buffer(self, b_lo, b_hi, b_w, b_gid):
+        """(union graph, its padded columns, live slots, pack mode) of
+        the live forest plus one batch."""
         U = self.union_edge_capacity
         lo_u = np.zeros(U, np.int32)
         hi_u = np.zeros(U, np.int32)
@@ -881,12 +888,21 @@ class StreamEngine:
 
         g = self._union_graph(lo_u, hi_u, w_u, valid_u)
         use_pack = self._use_pack()
+        return g, (lo_u, hi_u, w_u, gid_u, valid_u), m, use_pack
+
+    def _solve_and_commit(self, g, bufs, live_edges, use_pack, *,
+                          reset_reservoir=False):
+        """Solve one padded union graph (``stream.union.solve``, which
+        ends with the solve's result on the host) and rewrite the store
+        from it (``stream.union.publish``)."""
         # pack is a jit-static arg: flipping it re-traces even at an
         # already-seen buffer shape, so it is part of the executable key.
-        self._union_shapes.add((tuple(g.src.shape), use_pack))
+        self._union_shapes.add((tuple(g.src.shape), bool(use_pack)))
         self.last_union_shape = tuple(g.src.shape)
-        r = self._solve_graph(g, f + b, use_pack)
-        self._commit(r, lo_u, hi_u, w_u, gid_u, valid_u)
+        with obs.span("stream.union.solve"):
+            r = self._solve_graph(g, live_edges, bool(use_pack))
+        with obs.span("stream.union.publish"):
+            self._commit(r, *bufs, reset_reservoir=reset_reservoir)
         return r
 
     def _commit(
@@ -903,9 +919,10 @@ class StreamEngine:
         win = np.zeros(len(valid_u), bool)
         win[sel] = True
         lose = np.flatnonzero(valid_u & ~win)
-        if reset_reservoir:
+        if reset_reservoir:  # a rebuild from the whole surviving edge set
             self._reservoir.clear()
             self._lossy[:] = False
+            self._unhealed = 0
         else:
             # existing entries move to their merged components first, so
             # the per-component caps see the post-solve partition

@@ -1,16 +1,14 @@
-"""Observability (`repro.obs`) — tracer, registry, export, and the
-no-observer-effect contract (DESIGN.md §10).
+"""Observability (`repro.obs`) — spans on the profiler's clock, the
+metrics registry, and the no-observer-effect contract (DESIGN.md §10).
 
-The load-bearing suite here is the parity block: enabling ``obs="trace"``
-switches the flat and coarsen engines to phase-split execution
-(host-driven round/phase loops instead of the one-jit production paths),
-and these tests pin that the switch changes **no solver output bit** —
-weight, msf_eids, and parent must be identical across obs modes for
-every engine.
+Spans are TraceMe events: a ``jax.profiler`` session captured on the CPU
+and read back with ``ProfileData`` must show each path's span names,
+nested as the code nests them, and the same executables must give
+bit-identical outputs with the profiler on and off.
 """
 from __future__ import annotations
 
-import json
+import glob
 
 import numpy as np
 import pytest
@@ -22,82 +20,221 @@ from repro.graphs.structures import nx_free_n_components
 
 @pytest.fixture(autouse=True)
 def _clean_obs():
-    """Every test starts and ends with obs off and empty buffers."""
+    """Every test starts and ends with obs off and an empty registry."""
     obs.disable()
-    obs.reset()
     obs.metrics_reset()
     yield
     obs.disable()
-    obs.reset()
     obs.metrics_reset()
 
 
+def _profiled(tmp_path, fn):
+    """``fn()`` under a ``jax.profiler`` session (Python tracer off), and
+    the session's host events as (name, start_ns, end_ns, line)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            events += [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns), i)
+                       for e in line.events]
+    return out, events
+
+
 # ---------------------------------------------------------------------------
-# tracer
+# spans
 # ---------------------------------------------------------------------------
 
 
 def test_disabled_span_is_shared_noop_singleton():
-    # The disabled path is one branch + zero allocation: span() must
-    # return the same object every time, and it must be inert.
+    # With no profiler recording and metrics off, span() is one TraceMe
+    # activity check: the same inert object every time, no registry use.
     s1 = obs.span("a")
     s2 = obs.span("b", level=3)
     assert s1 is s2 is obs.NOOP_SPAN
     with s1 as sp:
-        assert sp.attach("payload") == "payload"
-        sp.set(anything="goes")
-    assert obs.trace_events() == []
+        assert sp is obs.NOOP_SPAN
     assert obs.metrics_snapshot()["histograms"] == {}
 
 
-def test_span_nesting_records_all_levels():
-    obs.enable("trace")
-    with obs.span("outer", level=0):
-        with obs.span("inner", level=1):
-            pass
-        with obs.span("inner", level=2):
-            pass
-    names = [e[0] for e in obs.trace_events()]
-    # Children exit (and record) before the parent.
-    assert names == ["inner", "inner", "outer"]
-    outer = next(e for e in obs.trace_events() if e[0] == "outer")
-    inner = [e for e in obs.trace_events() if e[0] == "inner"]
-    # Interval containment on the same thread — what Perfetto nests by.
-    for name, t0, dur, tid, _attrs in inner:
-        assert tid == outer[3]
-        assert outer[1] <= t0
-        assert t0 + dur <= outer[1] + outer[2]
-
-
 def test_enabled_is_upgrade_only():
-    obs.enable("trace")
-    with obs.enabled("metrics"):  # must NOT downgrade the global mode
-        assert obs.mode() == "trace"
-    with obs.enabled("off"):
-        assert obs.mode() == "trace"
+    obs.enable("metrics")
+    with obs.enabled("off"):  # must NOT downgrade the global mode
+        assert obs.mode() == "metrics"
     obs.disable()
     with obs.enabled("metrics"):
         assert obs.mode() == "metrics"
-        with obs.enabled("trace"):
-            assert obs.mode() == "trace"
-        assert obs.mode() == "metrics"
+        with obs.enabled("off"):
+            assert obs.mode() == "metrics"
     assert obs.mode() == "off"
+    with pytest.raises(ValueError):
+        obs.enable("trace")
 
 
-def test_collect_timings_aggregates_by_name():
-    obs.enable("metrics")
-    with obs.collect_timings() as t:
-        with obs.span("phase.a"):
-            pass
-        with obs.span("phase.a"):
-            pass
-        with obs.span("phase.b"):
-            pass
-    assert set(t) == {"phase.a", "phase.b"}
-    assert all(v >= 0.0 for v in t.values())
+def test_metrics_mode_feeds_span_histograms():
+    from repro.solve import SolveSpec, plan
+
+    p = plan(256, SolveSpec(mode="stream", obs="metrics"))
+    r = np.random.default_rng(5)
+    for _ in range(3):
+        p.update(r.integers(0, 256, 64), r.integers(0, 256, 64),
+                 r.random(64).astype(np.float32))
+    assert p.query(np.arange(8), np.arange(8, 16)).shape == (8,)
     h = obs.metrics_snapshot()["histograms"]
-    assert h["span.phase.a"]["count"] == 2
-    assert h["span.phase.b"]["count"] == 1
+    assert h["span.stream.update"]["count"] == 3
+    assert h["span.solve.stream.update"]["count"] == 3
+    assert {"p50", "p95", "p99"} <= set(h["span.stream.query"])
+    assert obs.mode() == "off"  # the spec's scope ended with each call
+
+
+def _flat():
+    from repro.solve import SolveSpec, plan
+    from repro.solve.planner import clear_plan_cache
+
+    clear_plan_cache()
+    return plan(random_graph(256, 1024, seed=7), SolveSpec()).solve()
+
+
+def _coarsen(fused):
+    from repro.coarsen import CoarsenConfig
+    from repro.solve import SolveSpec, plan
+
+    g = random_graph(512, 2048, seed=11)
+    cfg = CoarsenConfig(cutoff=32, rounds_per_level=2)
+    return plan(g, SolveSpec(mode="coarsen", coarsen=cfg, fused=fused)).solve()
+
+
+def _stream_plan():
+    from repro.solve import SolveSpec, plan
+
+    p = plan(256, SolveSpec(mode="stream"))
+    r = np.random.default_rng(5)
+    for _ in range(2):
+        p.update(r.integers(0, 256, 64).astype(np.int32),
+                 r.integers(0, 256, 64).astype(np.int32),
+                 r.random(64).astype(np.float32))
+    return p
+
+
+def _stream_update():
+    p = _stream_plan()
+    return p.update(np.arange(8), np.arange(8, 16), np.ones(8, np.float32))
+
+
+def _stream_query():
+    p = _stream_plan()
+    return p.query(np.arange(16), np.arange(16, 32))
+
+
+def _served_batch():
+    from repro import serve
+
+    p = _stream_plan()
+    handle = serve.start_in_thread(p, serve.ServeConfig())
+    try:
+        with serve.ServeClient(handle.address) as c:
+            return c.connected(list(range(8)), list(range(8, 16)))
+    finally:
+        handle.drain()
+
+
+# path -> (run, {parent span: spans that must nest inside one of them})
+SPAN_PATHS = {
+    "flat": (_flat, {
+        "solve.flat": {"msf.flat", "solve.fetch"},
+        None: {"plan.resolve", "plan.build", "plan.cost"},
+    }),
+    "coarsen_fused": (lambda: _coarsen(True), {
+        "solve.coarsen": {"coarsen.canonical", "coarsen.level",
+                          "coarsen.residual", "solve.fetch",
+                          "coarsen.finalize"},
+        "coarsen.residual": {"msf.flat"},
+    }),
+    "coarsen_unfused": (lambda: _coarsen(False), {
+        "solve.coarsen": {"coarsen.canonical", "coarsen.level",
+                          "coarsen.residual", "solve.fetch",
+                          "coarsen.finalize"},
+        "coarsen.residual": {"msf.flat"},
+    }),
+    "stream_update": (_stream_update, {
+        "solve.stream.update": {"stream.update"},
+        "stream.update": {"stream.union.prepare", "stream.union.solve",
+                          "stream.union.publish"},
+        "stream.union.solve": {"msf.flat"},
+    }),
+    "stream_query": (_stream_query, {
+        "solve.stream.query": {"stream.query"},
+    }),
+    "served_batch": (_served_batch, {
+        None: {"serve.decode", "serve.flush", "stream.query",
+               "serve.respond", "client.send", "client.recv"},
+    }),
+}
+
+
+@pytest.mark.parametrize("path", sorted(SPAN_PATHS))
+def test_profiler_trace_holds_nested_spans(path, tmp_path):
+    run, nesting = SPAN_PATHS[path]
+    _, events = _profiled(tmp_path, run)
+    for parent, children in nesting.items():
+        for child in children:
+            inner = [e for e in events if e[0] == child]
+            assert inner, f"{path}: no {child} span"
+            if parent is None:
+                continue
+            outer = [e for e in events if e[0] == parent]
+            for name, lo, hi, line in inner:
+                assert any(o[3] == line and o[1] <= lo and hi <= o[2]
+                           for o in outer), f"{path}: {child} outside {parent}"
+    if path == "served_batch":
+        # the loop's spans on one thread, the fused call on another
+        loop = {e[3] for e in events if e[0].startswith("serve.")}
+        assert len(loop) == 1
+        assert loop.isdisjoint(e[3] for e in events if e[0] == "stream.query")
+
+
+def test_span_attributes_keep_the_event_name(tmp_path):
+    # readers match fixed names; attributes land as the event's stats
+    def closed():
+        with obs.span("fixed.name", level=3, n=5):
+            pass
+
+    _, events = _profiled(tmp_path, closed)
+    assert [e[0] for e in events if "fixed" in e[0]] == ["fixed.name"]
+
+
+# ---------------------------------------------------------------------------
+# no-observer-effect parity: the profiler never changes solver output
+# ---------------------------------------------------------------------------
+
+
+def _assert_reports_identical(a, b, what):
+    assert float(a.weight) == float(b.weight), what
+    assert np.array_equal(np.asarray(a.msf_eids), np.asarray(b.msf_eids)), what
+    assert np.array_equal(np.asarray(a.parent), np.asarray(b.parent)), what
+
+
+@pytest.mark.parametrize("path", ["flat", "coarsen_fused", "coarsen_unfused",
+                                  "stream_update", "stream_query"])
+def test_profiler_parity(path, tmp_path):
+    run = SPAN_PATHS[path][0]
+    base = run()
+    traced, _ = _profiled(tmp_path, run)
+    if isinstance(base, np.ndarray):  # query answers
+        assert np.array_equal(base, traced)
+    else:
+        _assert_reports_identical(base, traced, f"{path} profiled")
 
 
 # ---------------------------------------------------------------------------
@@ -150,118 +287,6 @@ def test_histogram_rejects_bad_bounds():
         Histogram(bounds=())
     with pytest.raises(ValueError):
         Histogram(bounds=(1.0, 1.0))
-
-
-# ---------------------------------------------------------------------------
-# Chrome-trace export
-# ---------------------------------------------------------------------------
-
-
-def test_export_trace_schema_roundtrip(tmp_path):
-    obs.enable("trace")
-    with obs.span("outer", n=64):
-        with obs.span("inner"):
-            pass
-    path = str(tmp_path / "trace.json")
-    doc = obs.export_trace(path)
-    on_disk = json.loads(open(path).read())
-    assert on_disk == doc
-    complete = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-    assert {e["name"] for e in complete} == {"outer", "inner"}
-    for e in complete:
-        assert isinstance(e["ts"], float) and e["ts"] >= 0.0
-        assert isinstance(e["dur"], float) and e["dur"] >= 0.0
-        assert e["pid"] == 0 and isinstance(e["tid"], int)
-    outer = next(e for e in complete if e["name"] == "outer")
-    assert outer["args"] == {"n": 64}
-    # Metadata events name the process/threads for the Perfetto UI.
-    assert any(e.get("ph") == "M" for e in doc["traceEvents"])
-    assert doc["otherData"]["dropped_events"] == 0
-    # The repo's own CI validator must accept its own exporter's output.
-    import sys
-
-    sys.path.insert(0, "tools")
-    try:
-        from check_trace import check
-
-        assert check(path, ["outer", "inner"]) is None
-        assert check(path, ["absent-span"]) is not None
-    finally:
-        sys.path.remove("tools")
-
-
-# ---------------------------------------------------------------------------
-# no-observer-effect parity: obs must never change solver output
-# ---------------------------------------------------------------------------
-
-
-def _assert_reports_identical(a, b, what):
-    assert float(a.weight) == float(b.weight), what
-    assert np.array_equal(np.asarray(a.msf_eids), np.asarray(b.msf_eids)), what
-    assert np.array_equal(np.asarray(a.parent), np.asarray(b.parent)), what
-
-
-@pytest.mark.parametrize("fused", [False, True])
-def test_trace_parity_coarsen(fused):
-    from repro.coarsen import CoarsenConfig
-    from repro.solve import SolveSpec, plan
-
-    g = random_graph(512, 2048, seed=11)
-    cfg = CoarsenConfig(cutoff=32, rounds_per_level=2)
-    base = plan(g, SolveSpec(mode="coarsen", coarsen=cfg, fused=fused)).solve()
-    for mode in ("metrics", "trace"):
-        obs.reset()
-        rep = plan(
-            g, SolveSpec(mode="coarsen", coarsen=cfg, fused=fused, obs=mode)
-        ).solve()
-        _assert_reports_identical(base, rep, f"coarsen fused={fused} {mode}")
-        assert rep.timings and "solve.coarsen" in rep.timings
-    assert base.timings == {}
-    # The acceptance contract: the fused trace shows the per-level phases.
-    if fused:
-        names = {e[0] for e in obs.trace_events()}
-        assert {"coarsen.level", "coarsen.contract", "coarsen.relabel",
-                "coarsen.filter", "coarsen.residual"} <= names
-
-
-def test_trace_parity_flat():
-    from repro.solve import SolveSpec, plan
-
-    g = random_graph(256, 1024, seed=7)
-    base = plan(g, SolveSpec()).solve()
-    rep = plan(g, SolveSpec(obs="trace")).solve()
-    _assert_reports_identical(base, rep, "flat trace")
-    assert rep.timings["msf.round"] >= 0.0
-    names = [e[0] for e in obs.trace_events()]
-    # One span per hook+shortcut round, nested under msf.flat.
-    assert names.count("msf.round") == int(rep.iterations)
-    assert "msf.flat" in names
-
-
-def test_trace_parity_stream():
-    from repro.solve import SolveSpec, plan
-
-    rng = np.random.default_rng(3)
-    reports = []
-    for mode in ("off", "trace"):
-        p = plan(256, SolveSpec(mode="stream", obs=mode))
-        r = np.random.default_rng(5)
-        rep = None
-        for _ in range(3):
-            u = r.integers(0, 256, 64).astype(np.int32)
-            v = r.integers(0, 256, 64).astype(np.int32)
-            w = r.random(64).astype(np.float32)
-            rep = p.update(u, v, w)
-        reports.append(p.solve())
-        if mode == "trace":
-            conn = p.query(np.arange(8), np.arange(8, 16))
-            assert conn.shape == (8,)
-            h = obs.metrics_snapshot()["histograms"]
-            assert h["span.stream.update"]["count"] == 3
-            assert {"p50", "p95", "p99"} <= set(h["span.stream.query"])
-    _assert_reports_identical(reports[0], reports[1], "stream trace")
-    obs.disable()
-    del rng
 
 
 def test_trace_parity_dist(dist_mesh, dist_mesh_shape):

@@ -99,7 +99,6 @@ def test_measurement_carries_obs_snapshot_when_metrics_on():
         assert m.as_dict()["metrics"]["counters"]["x.y"] == 3
     finally:
         obs.disable()
-        obs.reset()
         obs.metrics_reset()
 
 
@@ -311,7 +310,6 @@ def test_microbatcher_obs_counters_and_gauge():
         assert snap["gauges"]["stream.batcher.queue_depth"] == 0
     finally:
         obs.disable()
-        obs.reset()
         obs.metrics_reset()
 
 
@@ -353,7 +351,6 @@ def test_loadgen_smoke_slo_report(tmp_path):
         ])
     finally:
         obs.disable()
-        obs.reset()
         obs.metrics_reset()
     assert rc == 0
     d = json.loads(out.read_text())
@@ -383,7 +380,6 @@ def test_loadgen_exits_nonzero_on_missed_slo(tmp_path):
         ])
     finally:
         obs.disable()
-        obs.reset()
         obs.metrics_reset()
     assert rc == 1
     d = json.loads(out.read_text())
