@@ -5,7 +5,9 @@ Two algorithm variants:
 - ``variant="complete"`` (production default, paper §IV-B): complete
   shortcutting keeps every tree a star at the top of each iteration, so the
   starcheck disappears and hooking can fuse the line-10 projection into the
-  multilinear kernel (segment ids = p[src] are root ids).
+  multilinear kernel (segment ids = p[src] are root ids). Unpacked, its
+  rounds hook through one rank per slot in the (w, eid) order, ranked
+  once per solve (DESIGN.md §2): one segment-min per round.
 - ``variant="paper"`` (faithful Algorithm 1): starcheck, per-vertex
   multilinear kernel (line 9), separate projection to roots (line 10), one
   shortcut round per iteration (line 15).
@@ -33,7 +35,9 @@ from repro.core import shortcut as sc
 from repro.core.multilinear import (
     min_outgoing_coo,
     min_outgoing_coo_packed,
+    min_outgoing_ranked,
     project_to_roots,
+    rank_slots,
 )
 from repro.core.semiring import INF, IMAX
 from repro.graphs.structures import Graph
@@ -88,10 +92,19 @@ def record_edges(msf_eids, n_f, keep, r_eid):
     return msf_eids, n_f + jnp.sum(keep.astype(jnp.int32))
 
 
-def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin):
+def _ranked(variant: str, pack: bool) -> bool:
+    """Whether the driver hooks through the slot ranks: the unpacked
+    complete variant (the paper and pairwise baselines keep the 3-pass
+    reduction, the packed path its single packed key)."""
+    return variant == "complete" and not pack
+
+
+def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin, ranks=None):
     """One hook+shortcut round as ``body(state) -> state`` over the
     6-tuple ``(p, total, msf_eids, n_f, it, done)``: the body of
-    :func:`_msf_jit`'s while_loop."""
+    :func:`_msf_jit`'s while_loop. ``ranks`` are the graph's
+    :func:`~repro.core.multilinear.rank_slots` tables, which the unpacked
+    complete variant hooks through."""
     n = graph.n
     src, dst, w, eid, valid = graph.src, graph.dst, graph.w, graph.eid, graph.valid
 
@@ -133,7 +146,7 @@ def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin):
             return min_outgoing_coo_packed(
                 p, src, dst, w, eid, valid, n, segmin=segmin
             )
-        return min_outgoing_coo(p, src, dst, w, eid, valid, n, segment="root")
+        return min_outgoing_ranked(p, src, dst, w, eid, valid, n, ranks)
 
     def body_paper(state):
         p, total, msf_eids, n_f, it, _ = state
@@ -200,7 +213,9 @@ def _msf_jit(
     """Jitted MSF driver — see :func:`msf` for the public entry point."""
     limit = jnp.int32(_msf_limit(graph.n, max_iters))
     shortcut_fn = sc.make_shortcut_fn(shortcut, capacity) if variant != "paper" else None
-    body = _make_msf_body(graph, variant, shortcut_fn, pack, segmin)
+    # Ranked once per solve, before the loop: every solve pays for its own.
+    ranks = rank_slots(graph.w, graph.eid, graph.valid) if _ranked(variant, pack) else None
+    body = _make_msf_body(graph, variant, shortcut_fn, pack, segmin, ranks)
 
     def cond(state):
         _, _, _, _, it, done = state
@@ -224,6 +239,8 @@ def run_flat(graph: Graph, **kw) -> MSFResult:
     twice)."""
     from repro import obs
 
+    if _ranked(kw.get("variant", "complete"), kw.get("pack", False)):
+        obs.counter("msf.reduction.ranked").inc()
     with obs.span("msf.flat"):
         r = _msf_jit(graph, **kw)
         int(r.n_msf_edges)

@@ -120,3 +120,59 @@ def test_empty_and_singleton():
     r = msf(g)
     assert float(r.weight) == 0.0
     assert int(r.n_msf_edges) == 0
+
+
+def _kruskal_eids(g):
+    """scipy's minimum spanning forest under the strict (w, eid) order:
+    each undirected edge keyed by its 1-based rank in that order."""
+    import scipy.sparse as sp
+    import scipy.sparse.csgraph as csg
+
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    up = np.asarray(g.valid) & (src < dst)
+    lo, hi = src[up], dst[up]
+    w, eid = np.asarray(g.w)[up] + 0.0, np.asarray(g.eid)[up]
+    rank = np.empty(len(w), np.float64)
+    rank[np.lexsort((eid, w))] = np.arange(1, len(w) + 1)
+    f = csg.minimum_spanning_tree(
+        sp.csr_matrix((rank, (lo, hi)), shape=(g.n, g.n))).tocoo()
+    by_rank = dict(zip(rank.astype(np.int64).tolist(), eid.tolist()))
+    return set(by_rank[int(r)] for r in f.data)
+
+
+def _same_partition(a, b):
+    a, b = np.asarray(a, np.int64), np.asarray(b, np.int64)
+    pairs = len(np.unique(a * (int(b.max()) + 1) + b))
+    return pairs == len(np.unique(a)) == len(np.unique(b))
+
+
+@pytest.mark.parametrize("weights", ["ties", "float", "signed_zero"])
+def test_flat_forest_matches_3pass_paths_and_kruskal(weights):
+    """The flat plan (rank-keyed hook) picks the same forest, weight and
+    labels as the paths that keep the 3-pass reduction — the paper
+    variant and the fused coarsen levels — and as scipy's Kruskal."""
+    from repro.coarsen import CoarsenConfig
+    from repro.solve import SolveSpec, plan
+
+    rng = np.random.default_rng(11)
+    n, m = 300, 1200
+    u, v = rng.integers(0, n, m), rng.integers(0, n, m)
+    if weights == "ties":
+        w = rng.integers(1, 4, m).astype(np.float64)
+    elif weights == "float":
+        w = rng.random(m)
+    else:
+        w = rng.choice(np.array([-0.0, 0.0, 2.5]), m)
+    g = from_edges(u, v, w, n)
+    flat = plan(g, SolveSpec(pack=False)).solve()
+    paper = plan(g, SolveSpec(pack=False, variant="paper")).solve()
+    fused = plan(g, SolveSpec(mode="coarsen", pack=False, fused=True,
+                              coarsen=CoarsenConfig(cutoff=16))).solve()
+    assert fused.levels, "the coarsen levels did not run"
+    want = _kruskal_eids(g)
+    for other in (paper, fused):
+        assert set(other.msf_eids.tolist()) == want
+        assert _same_partition(flat.parent, other.parent)
+        assert flat.weight == pytest.approx(other.weight, rel=1e-6, abs=1e-6)
+    assert set(flat.msf_eids.tolist()) == want
+    assert flat.n_msf_edges == len(want) == n - nx_free_n_components(g)

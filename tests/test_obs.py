@@ -367,3 +367,17 @@ def test_n_components_matches_graph_truth():
     while not np.array_equal(p[p], p):
         p = p[p]
     assert rep.n_components == len(np.unique(p))
+
+
+def test_ranked_reduction_counter_counts_unpacked_flat_solves():
+    """``msf.reduction.ranked`` counts one per flat solve that hooks
+    through the slot ranks: unpacked and complete, not pack32, not the
+    paper variant."""
+    from repro.solve import SolveSpec, plan
+
+    g = random_graph(128, 512, seed=4)
+    plan(g, SolveSpec(pack=False)).solve()
+    plan(g, SolveSpec(pack=False)).solve()
+    plan(g, SolveSpec(pack=True)).solve()
+    plan(g, SolveSpec(pack=False, variant="paper")).solve()
+    assert obs.metrics_snapshot()["counters"]["msf.reduction.ranked"] == 2

@@ -60,12 +60,16 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel_fits(compiled, chip):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_fits(compiled, chip):
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
     (device,) = chip.device_set
     assert total < peaks(device.device_kind)["hbm_bytes"], total
+
+
+def _assert_kernel_fits(compiled, chip):
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_fits(compiled, chip)
 
 
 @pytest.mark.parametrize("kernel", ["flat", "sorted"])
@@ -143,6 +147,28 @@ def test_packed_flat_driver_compiles_for_v5e(one_chip):
             g, pack=True, segmin=_mosaic_segmin("flat")
         ).compile()
     _assert_kernel_fits(compiled, one_chip)
+
+
+def test_unpacked_flat_driver_compiles_for_v5e(one_chip):
+    """The unpacked AS driver above pack32's 2^24 slots: the slot-rank
+    sorts before the loop and the rank-keyed hook inside it."""
+    from repro.core.msf import _msf_jit
+    from repro.graphs.structures import Graph
+
+    n, e = 1 << 20, (1 << 24) + 1024
+    g = Graph(
+        src=_spec(one_chip, (e,), jnp.int32),
+        dst=_spec(one_chip, (e,), jnp.int32),
+        w=_spec(one_chip, (e,), jnp.float32),
+        eid=_spec(one_chip, (e,), jnp.int32),
+        valid=_spec(one_chip, (e,), jnp.bool_),
+        n=n,
+    )
+    with _no_persistent_cache():
+        compiled = _msf_jit.lower(g, pack=False).compile()
+    text = compiled.as_text()
+    assert "/rank/sort" in text and "/hook/segmin/scatter-min" in text
+    _assert_fits(compiled, one_chip)
 
 
 def test_fused_coarsen_level_compiles_for_v5e(one_chip):
