@@ -228,29 +228,6 @@ def _msf_jit(
     return MSFResult(weight=total, parent=p, msf_eids=msf_eids, n_msf_edges=n_f, iterations=it)
 
 
-def slot_bucket(e: int) -> int:
-    """The slot count a flat plan compiles for: ``e`` rounded up to a
-    multiple of 1/64 of its next power of two (at most 1.6% more slots),
-    so graphs of about one size share one executable and its cached
-    compile — the rank sorts take the TPU compiler tens of seconds."""
-    step = max(1, (1 << max(e - 1, 0).bit_length()) >> 6)
-    return -(-e // step) * step
-
-
-@partial(jax.jit, static_argnames="e_pad")
-def pad_slots(graph: Graph, e_pad: int) -> Graph:
-    """:meth:`Graph.pad_to` on the device: invalid slots up to ``e_pad``."""
-    pad = (0, e_pad - graph.src.shape[0])
-    return Graph(
-        src=jnp.pad(graph.src, pad),
-        dst=jnp.pad(graph.dst, pad),
-        w=jnp.pad(graph.w, pad, constant_values=INF),
-        eid=jnp.pad(graph.eid, pad, constant_values=IMAX),
-        valid=jnp.pad(graph.valid, pad),
-        n=graph.n,
-    )
-
-
 def run_flat(graph: Graph, **kw) -> MSFResult:
     """The jitted flat driver, for callers holding a *resolved* segmin
     callable (the ``repro.solve`` flat engine, :func:`flat_msf`, the

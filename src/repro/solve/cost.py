@@ -184,10 +184,7 @@ def lower_plan(mode: str, target, rs):
     solve itself needs (and hits a warm persistent compile cache). Raises
     on anything it cannot lower — callers that must not fail wrap it."""
     if mode == "flat":
-        from repro.core.msf import slot_bucket
-
-        e = slot_bucket(int(np.asarray(target.src).shape[0]))
-        return _lower_flat(int(target.n), e, rs)
+        return _lower_flat(int(target.n), int(np.asarray(target.src).shape[0]), rs)
     if mode == "coarsen":
         return _lower_coarsen(target, rs)
     raise ValueError(f"no dominant-executable lowering for mode {mode!r}")

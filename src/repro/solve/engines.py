@@ -29,12 +29,9 @@ class _FlatEngine:
         self._rs = rs
 
     def solve(self, graph, parent0=None) -> SolveReport:
-        from repro.core.msf import pad_slots, run_flat, slot_bucket
+        from repro.core.msf import run_flat
 
         rs, s = self._rs, self._rs.spec
-        e = int(graph.src.shape[0])
-        if slot_bucket(e) != e:
-            graph = pad_slots(graph, slot_bucket(e))
         r = run_flat(
             graph,
             parent0=parent0,

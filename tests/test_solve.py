@@ -190,27 +190,6 @@ def test_plan_cache_same_spec_same_shape_reuses_executable():
     assert plan(g_same, spec)._engine is p1._engine
 
 
-def test_flat_plans_in_one_slot_bucket_share_one_executable():
-    """Slot counts that round up to one bucket compile the flat driver
-    once, and the padding slots change no result."""
-    from repro.core.msf import _msf_jit, flat_msf, slot_bucket
-
-    spec = SolveSpec(max_iters=39, pack=False)  # unique static → fresh executable
-    g1 = _graph(n=200, m=610, seed=5, float_w=True)
-    g2 = _graph(n=200, m=612, seed=6, float_w=True)
-    e1, e2 = g1.num_directed_edges, g2.num_directed_edges
-    assert e1 != e2 and slot_bucket(e1) == slot_bucket(e2) > max(e1, e2)
-    got = [plan(g1, spec).solve()]
-    compiled = _msf_jit._cache_size()
-    got.append(plan(g2, spec).solve())
-    assert _msf_jit._cache_size() == compiled, "one bucket, one executable"
-    for g, r in zip((g1, g2), got):
-        want = flat_msf(g)  # the driver at the exact slot count
-        assert set(r.msf_eids.tolist()) == set(
-            np.asarray(want.msf_eids)[: int(want.n_msf_edges)].tolist())
-        np.testing.assert_array_equal(r.parent, np.asarray(want.parent))
-
-
 def test_plan_cache_misses_on_shape_spec_or_resolution():
     clear_plan_cache()
     spec = SolveSpec(max_iters=37)
