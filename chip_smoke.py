@@ -134,9 +134,9 @@ def check_forest(g, rep, ref_weight: float, ref_ncc: int, what: str) -> dict:
         ref_components=ref_ncc, msf_edges=len(f),
     )
     check(exact == ref_weight, f"{what}: MSF weight {exact} != reference {ref_weight}")
-    # the reported scalar is a float32 accumulation
-    check(abs(float(rep.weight) - ref_weight) <= max(1.0, 1e-5 * ref_weight),
-          f"{what}: reported weight {rep.weight} != reference {ref_weight}")
+    # the reported weight is the float64 sum of the same edges' weights
+    check(float(rep.weight) == exact,
+          f"{what}: reported weight {rep.weight} != its edges' {exact}")
     check(int(rep.n_components) == ref_ncc,
           f"{what}: {rep.n_components} components != reference {ref_ncc}")
     check(len(f) == g.n - ref_ncc and ncc_forest == ref_ncc,

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import shortcut as sc
-from repro.core.msf import hook_and_tiebreak, record_edges
+from repro.core.msf import FOREST_WEIGHT, hook_and_tiebreak, record_edges
 from repro.core.multilinear import min_outgoing_coo, min_outgoing_coo_packed
 from repro.core.semiring import IMAX
 from repro.coarsen.relabel import rank_relabel
@@ -31,9 +31,11 @@ class ContractResult(NamedTuple):
     parent: jax.Array  # int32 [n]: star-canonical labels after K rounds
     new_ids: jax.Array  # int32 [n]: vertex → supervertex rank in [0, n_next)
     n_next: jax.Array  # int32 scalar: supervertex count
-    weight: jax.Array  # float32 scalar: weight hooked this level
     msf_eids: jax.Array  # int32 [n]: global eids chosen this level (front-packed)
+    msf_w: jax.Array  # float32 [n]: their weights, at the same positions
     n_msf_edges: jax.Array  # int32 scalar
+
+    weight = FOREST_WEIGHT  # host float64 sum of the level's hooked weights
 
 
 def contract_rounds(reduce_fn, n: int, rounds: int) -> ContractResult:
@@ -46,14 +48,14 @@ def contract_rounds(reduce_fn, n: int, rounds: int) -> ContractResult:
     rank/relabel) operates on replicated dense vectors and is shared."""
     with jax.named_scope("contract"):
         p = jnp.arange(n, dtype=jnp.int32)
-        total = jnp.float32(0.0)
         msf_eids = jnp.full((n,), IMAX, jnp.int32)
+        msf_w = jnp.zeros((n,), jnp.float32)
         n_f = jnp.int32(0)
         for _ in range(rounds):
             r = reduce_fn(p)
             p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
-            total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
-            msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
+            msf_eids, msf_w, n_f = record_edges(msf_eids, msf_w, n_f, keep,
+                                                r.eid, r.w)
             p = sc.complete_shortcut(p_h)
     with jax.named_scope("relabel"):
         new_ids, n_next = rank_relabel(p)
@@ -61,8 +63,8 @@ def contract_rounds(reduce_fn, n: int, rounds: int) -> ContractResult:
         parent=p,
         new_ids=new_ids,
         n_next=n_next,
-        weight=total,
         msf_eids=msf_eids,
+        msf_w=msf_w,
         n_msf_edges=n_f,
     )
 

@@ -58,7 +58,7 @@ from repro import obs
 from repro.compat import shard_map
 from repro.coarsen.contract import contract_rounds, make_und_reduce
 from repro.coarsen.config import CoarsenConfig
-from repro.coarsen.engine import LevelStats, _next_pow2
+from repro.coarsen.engine import LevelStats, _concat, _next_pow2, forest_result
 from repro.coarsen.filter import filter_level_host, filter_level_impl
 from repro.coarsen.relabel import canonical_minvertex_labels
 from repro.core.msf import MSFResult, hook_and_tiebreak, record_edges
@@ -146,7 +146,7 @@ def _level_driver(
         m_total = lax.psum(lax.psum(m_local, col_axis), row_axis)
         return (
             tuple(a.reshape(shp) for a in out)
-            + (res.new_ids[label_map], res.new_ids, res.n_next, res.weight,
+            + (res.new_ids[label_map], res.new_ids, res.n_next, res.msf_w,
                res.msf_eids, res.n_msf_edges, m_max, m_total)
         )
 
@@ -178,28 +178,28 @@ def _residual_driver(
         )
 
         def body(state):
-            p, total, msf_eids, n_f, it, _ = state
+            p, msf_eids, msf_w, n_f, it, _ = state
             r = reduce_fn(p)
             p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
-            total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
-            msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
+            msf_eids, msf_w, n_f = record_edges(msf_eids, msf_w, n_f, keep,
+                                                r.eid, r.w)
             p_next = complete_shortcut(p_h)
             done = ~jnp.any(keep)
-            return p_next, total, msf_eids, n_f, it + 1, done
+            return p_next, msf_eids, msf_w, n_f, it + 1, done
 
         def cond(state):
             return jnp.logical_and(~state[5], state[4] < limit)
 
         init = (
             jnp.arange(n, dtype=jnp.int32),
-            jnp.float32(0.0),
             jnp.full((n,), IMAX, jnp.int32),
+            jnp.zeros((n,), jnp.float32),
             jnp.int32(0),
             jnp.int32(0),
             jnp.bool_(False),
         )
-        p, total, msf_eids, n_f, it, _ = lax.while_loop(cond, body, init)
-        return p, total, msf_eids, n_f, it
+        p, msf_eids, msf_w, n_f, it, _ = lax.while_loop(cond, body, init)
+        return p, msf_w, msf_eids, n_f, it
 
     specs_e = P(row_axis, col_axis, None)
     mapped = shard_map(
@@ -328,8 +328,8 @@ class DistCoarsenMSF:
         mesh_key = (self.mesh, self.row_axis, self.col_axis)
         n_cur = n0
         m_cur = int(valid_np.sum())
-        weight = 0.0
         eids_acc: list[np.ndarray] = []
+        w_acc: list[np.ndarray] = []
         stats: list[LevelStats] = []
         roundtrips = 0
 
@@ -349,7 +349,7 @@ class DistCoarsenMSF:
                     break
                 n_f = int(out[10])
                 eids_acc.append(np.asarray(out[9][:n_f]))
-                weight += float(out[8])
+                w_acc.append(np.asarray(out[8][:n_f]))
             if in_mesh:
                 m_max, m_total = int(out[11]), int(out[12])
                 cap = _next_pow2(m_max)
@@ -379,17 +379,15 @@ class DistCoarsenMSF:
             *mesh_key, n_res_pad, eid_cap, use_pack, segmin_hook, limit
         )
         with obs.span("dist.residual", n=n_cur, m=m_cur):
-            p_res, r_weight, r_eids, r_nf, r_it = rdrv(
+            p_res, r_w, r_eids, r_nf, r_it = rdrv(
                 lo, hi, w_b, eid_b, valid_b
             )
             # Residual rounds run the same per-round combine schedule.
             _account_allreduce(int(r_it), n_res_pad, use_pack)
 
-        all_eids = np.concatenate(
-            eids_acc + [np.asarray(r_eids[: int(r_nf)])]
-        ) if eids_acc or int(r_nf) else np.zeros(0, np.int32)
-        msf_eids = np.full(n0, _IMAX_NP, np.int32)
-        msf_eids[: len(all_eids)] = all_eids
+        r_nf = int(r_nf)
+        eids_acc.append(np.asarray(r_eids)[:r_nf])
+        w_acc.append(np.asarray(r_w)[:r_nf])
         comp = np.asarray(p_res)[np.asarray(label_map)]
         self.last_stats = DistCoarsenStats(
             levels=tuple(stats),
@@ -398,12 +396,10 @@ class DistCoarsenMSF:
             residual_iters=int(r_it),
             host_roundtrips=roundtrips,
         )
-        return MSFResult(
-            weight=np.float32(weight + float(r_weight)),
-            parent=canonical_minvertex_labels(comp, n_res_pad),
-            msf_eids=msf_eids,
-            n_msf_edges=np.int32(len(all_eids)),
-            iterations=np.int32(
-                len(stats) * cfg.rounds_per_level + int(r_it)
-            ),
+        return forest_result(
+            canonical_minvertex_labels(comp, n_res_pad),
+            _concat(eids_acc, np.int32),
+            _concat(w_acc, np.float32),
+            n0,
+            len(stats) * cfg.rounds_per_level + int(r_it),
         )

@@ -65,8 +65,8 @@ class CoarsenStats(NamedTuple):
 class CoarsenPrelude(NamedTuple):
     """Everything the contraction levels decided, residual not yet solved."""
 
-    weight: float  # MSF weight hooked across all levels
     msf_eids: np.ndarray  # global eids of level-hooked MSF edges
+    msf_w: np.ndarray  # float32: their weights, in the same order
     label_map: np.ndarray  # int32 [n0]: original vertex → residual vertex id
     residual: Graph  # canonical symmetric residual graph
     stats: CoarsenStats
@@ -122,8 +122,8 @@ class FusedLevel(NamedTuple):
     m_new: jax.Array  # int32 scalar: unique live pairs
     new_ids: jax.Array  # int32 [n]: vertex → supervertex rank
     n_next: jax.Array  # int32 scalar: supervertex count (incl. padding roots)
-    weight: jax.Array  # float32 scalar: weight hooked this level
     msf_eids: jax.Array  # int32 [n]: global eids hooked (front-packed)
+    msf_w: jax.Array  # float32 [n]: their weights, at the same positions
     n_msf_edges: jax.Array  # int32 scalar
     label_map: jax.Array  # int32 [n0]: original vertex → supervertex id
 
@@ -197,8 +197,8 @@ def fused_level(
         m_new=fr.m_new,
         new_ids=res.new_ids,
         n_next=res.n_next,
-        weight=res.weight,
         msf_eids=res.msf_eids,
+        msf_w=res.msf_w,
         n_msf_edges=res.n_msf_edges,
         label_map=res.new_ids[label_map],
     )
@@ -217,8 +217,8 @@ def _run_levels_fused(
     lo, hi, w, eid, valid, m_cur = canon
     label_map = jnp.arange(n0, dtype=jnp.int32)
 
-    weight = 0.0
     eids_acc: list[np.ndarray] = []
+    w_acc: list[np.ndarray] = []
     stats: list[LevelStats] = []
     n_cur = n0
 
@@ -238,7 +238,7 @@ def _run_levels_fused(
                 break
             n_f = int(res.n_msf_edges)
             eids_acc.append(np.asarray(res.msf_eids[:n_f]))
-            weight += float(res.weight)
+            w_acc.append(np.asarray(res.msf_w[:n_f]))
             m_next = int(res.m_new)
         pad = _next_pow2(m_next)
         lo, hi, w, eid, valid = (
@@ -256,10 +256,8 @@ def _run_levels_fused(
     with obs.span("solve.fetch"):
         label_map = np.asarray(label_map)
     return CoarsenPrelude(
-        weight=weight,
-        msf_eids=(
-            np.concatenate(eids_acc) if eids_acc else np.zeros(0, np.int32)
-        ),
+        msf_eids=_concat(eids_acc, np.int32),
+        msf_w=_concat(w_acc, np.float32),
         label_map=label_map,
         residual=residual,
         stats=CoarsenStats(levels=tuple(stats), residual_n=n_cur,
@@ -293,8 +291,8 @@ def run_levels(graph: Graph, config: CoarsenConfig | None = None) -> CoarsenPrel
     dedupe = resolve_dedupe(cfg.dedupe)
 
     label_map = np.arange(n0, dtype=np.int32)
-    weight = 0.0
     eids_acc: list[np.ndarray] = []
+    w_acc: list[np.ndarray] = []
     stats: list[LevelStats] = []
     n_cur = n0
 
@@ -319,7 +317,7 @@ def run_levels(graph: Graph, config: CoarsenConfig | None = None) -> CoarsenPrel
                 break
             n_f = int(res.n_msf_edges)
             eids_acc.append(np.asarray(res.msf_eids[:n_f]))
-            weight += float(res.weight)
+            w_acc.append(np.asarray(res.msf_w[:n_f]))
             if dedupe == "host":
                 l2, h2, w2_, e2_ = filter_level_host(
                     lo, hi, w, eid, valid, res.new_ids, n_cur
@@ -357,10 +355,8 @@ def run_levels(graph: Graph, config: CoarsenConfig | None = None) -> CoarsenPrel
         lo, hi, w, eid, valid, next_pow2(n_cur, floor=8)
     )
     return CoarsenPrelude(
-        weight=weight,
-        msf_eids=(
-            np.concatenate(eids_acc) if eids_acc else np.zeros(0, np.int32)
-        ),
+        msf_eids=_concat(eids_acc, np.int32),
+        msf_w=_concat(w_acc, np.float32),
         label_map=label_map,
         residual=residual,
         stats=CoarsenStats(levels=tuple(stats), residual_n=n_cur,
@@ -369,28 +365,50 @@ def run_levels(graph: Graph, config: CoarsenConfig | None = None) -> CoarsenPrel
     )
 
 
+def _concat(parts: list, dtype) -> np.ndarray:
+    return np.concatenate(parts) if parts else np.zeros(0, dtype)
+
+
+def forest_result(parent, eids, w, n0: int, iterations: int,
+                  shortcut_jumps: int = 0) -> MSFResult:
+    """A host ``MSFResult`` of ``n0`` vertices from the forest's edge ids
+    and their weights (in the same order), padded as the flat driver pads."""
+    msf_eids = np.full(n0, _IMAX, np.int32)
+    msf_eids[: len(eids)] = eids
+    msf_w = np.zeros(n0, np.float32)
+    msf_w[: len(w)] = w
+    return MSFResult(
+        parent=parent,
+        msf_eids=msf_eids,
+        msf_w=msf_w,
+        n_msf_edges=np.int32(len(eids)),
+        iterations=np.int32(iterations),
+        shortcut_jumps=np.int32(shortcut_jumps),
+    )
+
+
 def _finalize(
     prelude: CoarsenPrelude,
     residual_parent: np.ndarray,
     residual_eids: np.ndarray,
-    residual_weight: float,
+    residual_w: np.ndarray,
     residual_iters: int,
+    residual_jumps: int,
     n0: int,
 ) -> MSFResult:
     """Merge level picks with the residual solve into one MSFResult in
-    original-graph vertex/edge ids."""
+    original-graph vertex/edge ids. ``shortcut_jumps`` is the residual
+    flat solve's: the levels do not count theirs."""
     from repro.coarsen.relabel import canonical_minvertex_labels
 
-    all_eids = np.concatenate([prelude.msf_eids, residual_eids])
-    msf_eids = np.full(n0, _IMAX, np.int32)
-    msf_eids[: len(all_eids)] = all_eids
     comp = residual_parent[prelude.label_map]  # [n0] residual-space labels
-    return MSFResult(
-        weight=np.float32(prelude.weight + residual_weight),
-        parent=canonical_minvertex_labels(comp, len(residual_parent)),
-        msf_eids=msf_eids,
-        n_msf_edges=np.int32(len(all_eids)),
-        iterations=np.int32(prelude.level_iters + residual_iters),
+    return forest_result(
+        canonical_minvertex_labels(comp, len(residual_parent)),
+        np.concatenate([prelude.msf_eids, residual_eids]),
+        np.concatenate([prelude.msf_w, residual_w]),
+        n0,
+        prelude.level_iters + residual_iters,
+        residual_jumps,
     )
 
 
@@ -424,11 +442,13 @@ class CoarsenMSF:
             r = _flat_msf(prelude.residual, **self.msf_kw)
         self.last_stats = prelude.stats
         with obs.span("solve.fetch"):
+            n_f = int(r.n_msf_edges)
             parts = (
                 np.asarray(r.parent),
-                np.asarray(r.msf_eids)[: int(r.n_msf_edges)],
-                float(r.weight),
+                np.asarray(r.msf_eids)[:n_f],
+                np.asarray(r.msf_w)[:n_f],
                 int(r.iterations),
+                int(r.shortcut_jumps),
             )
         with obs.span("coarsen.finalize"):
             return _finalize(prelude, *parts, graph.n)
@@ -496,11 +516,13 @@ def merge_distributed(prelude: CoarsenPrelude, dist_result) -> MSFResult:
     to hard-code one round per level and under-report whenever
     ``rounds_per_level > 1``.
     """
+    n_f = int(dist_result.n_msf_edges)
     return _finalize(
         prelude,
         np.asarray(dist_result.parent),
-        np.asarray(dist_result.msf_eids)[: int(dist_result.n_msf_edges)],
-        float(dist_result.weight),
+        np.asarray(dist_result.msf_eids)[:n_f],
+        np.asarray(dist_result.msf_w)[:n_f],
         int(dist_result.iterations),
+        0,  # the distributed driver counts no pointer jumps
         len(prelude.label_map),
     )

@@ -20,8 +20,11 @@ Termination uses FastSV's grandparent-convergence condition (paper §V): stop
 when hooking makes no progress, checked on the parent vector after complete
 shortcutting.
 
-Outputs: total MSF weight, the MSF edge set (global eids), parent vector
-(connected-component labels), and iteration count.
+Outputs: the MSF edge set (global eids) with each edge's weight beside it,
+the parent vector (connected-component labels), the iteration count and
+the shortcut's pointer jumps. The total weight is summed on the host in
+float64 from the recorded edge weights (:func:`forest_weight`), so it is
+exact for integer weights whatever the total (DESIGN.md §2).
 """
 from __future__ import annotations
 
@@ -30,6 +33,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import shortcut as sc
 from repro.core.multilinear import (
@@ -43,12 +47,33 @@ from repro.core.semiring import INF, IMAX
 from repro.graphs.structures import Graph
 
 
+def forest_weight(w, count=None) -> float:
+    """Total weight of a forest: the float64 sum, on the host, of its
+    edges' float32 weights ``w[:count]``. Exact for integer weights while
+    the total is below 2^53; for float weights the float64 sum of the
+    float32 values. Every engine's forest weight comes from here."""
+    w = np.asarray(w)
+    if count is not None:
+        w = w[: int(count)]
+    return float(w.sum(dtype=np.float64))
+
+
+# ``weight`` of every record that carries ``msf_w`` and ``n_msf_edges``.
+FOREST_WEIGHT = property(
+    lambda r: forest_weight(r.msf_w, r.n_msf_edges),
+    doc="Total forest weight: :func:`forest_weight` of the recorded edges.",
+)
+
+
 class MSFResult(NamedTuple):
-    weight: jax.Array  # float32 scalar: total MSF weight
     parent: jax.Array  # int32 [n]: component representative per vertex
     msf_eids: jax.Array  # int32 [n]: global eids of MSF edges, IMAX padded
+    msf_w: jax.Array  # float32 [n]: each MSF edge's weight beside its eid, 0 padded
     n_msf_edges: jax.Array  # int32 scalar
     iterations: jax.Array  # int32 scalar
+    shortcut_jumps: jax.Array  # int32 scalar: p ← p[p] passes of the flat driver
+
+    weight = FOREST_WEIGHT
 
 
 def starcheck(p: jax.Array) -> jax.Array:
@@ -83,13 +108,15 @@ def hook_and_tiebreak(p, r_w, r_eid, r_parent):
     return p_new, keep, t
 
 
-def record_edges(msf_eids, n_f, keep, r_eid):
-    """Append the surviving hook edges' eids to the MSF buffer."""
+def record_edges(msf_eids, msf_w, n_f, keep, r_eid, r_w):
+    """Append the surviving hook edges' eids, and their weights at the
+    same positions, to the MSF buffers."""
     n = keep.shape[0]
     pos = n_f + jnp.cumsum(keep.astype(jnp.int32)) - 1
     tgt = jnp.where(keep, pos, n)  # drop non-winners
     msf_eids = msf_eids.at[tgt].set(r_eid, mode="drop")
-    return msf_eids, n_f + jnp.sum(keep.astype(jnp.int32))
+    msf_w = msf_w.at[tgt].set(r_w, mode="drop")
+    return msf_eids, msf_w, n_f + jnp.sum(keep.astype(jnp.int32))
 
 
 def _ranked(variant: str, pack: bool) -> bool:
@@ -101,7 +128,7 @@ def _ranked(variant: str, pack: bool) -> bool:
 
 def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin, ranks=None):
     """One hook+shortcut round as ``body(state) -> state`` over the
-    6-tuple ``(p, total, msf_eids, n_f, it, done)``: the body of
+    7-tuple ``(p, msf_eids, msf_w, n_f, it, jumps, done)``: the body of
     :func:`_msf_jit`'s while_loop. ``ranks`` are the graph's
     :func:`~repro.core.multilinear.rank_slots` tables, which the unpacked
     complete variant hooks through."""
@@ -109,17 +136,17 @@ def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin, ranks=None)
     src, dst, w, eid, valid = graph.src, graph.dst, graph.w, graph.eid, graph.valid
 
     def body_complete(state):
-        p, total, msf_eids, n_f, it, _ = state
+        p, msf_eids, msf_w, n_f, it, jumps, _ = state
         p_prev = p
         with jax.named_scope("hook"):
             r = min_outgoing(p)
             p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
-            total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
-            msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
+            msf_eids, msf_w, n_f = record_edges(msf_eids, msf_w, n_f, keep,
+                                                r.eid, r.w)
         with jax.named_scope("shortcut"):
-            p_next = shortcut_fn(p_h, p_prev)
+            p_next, k = shortcut_fn(p_h, p_prev)
         done = jnp.all(p_next == p_prev)
-        return p_next, total, msf_eids, n_f, it + 1, done
+        return p_next, msf_eids, msf_w, n_f, it + 1, jumps + k, done
 
     def min_outgoing(p):
         if variant == "pairwise":
@@ -149,35 +176,35 @@ def _make_msf_body(graph: Graph, variant, shortcut_fn, pack, segmin, ranks=None)
         return min_outgoing_ranked(p, src, dst, w, eid, valid, n, ranks)
 
     def body_paper(state):
-        p, total, msf_eids, n_f, it, _ = state
+        p, msf_eids, msf_w, n_f, it, jumps, _ = state
         p_prev = p
         s = starcheck(p)
         q = min_outgoing_coo(p, src, dst, w, eid, valid, n, segment="vertex", star=s)
         r = project_to_roots(q, p, n)
         p_h, keep, _ = hook_and_tiebreak(p, r.w, r.eid, r.payload[0])
-        total = total + jnp.sum(jnp.where(keep, r.w, 0.0))
-        msf_eids, n_f = record_edges(msf_eids, n_f, keep, r.eid)
+        msf_eids, msf_w, n_f = record_edges(msf_eids, msf_w, n_f, keep, r.eid, r.w)
         s2 = starcheck(p_h)
-        p_next = sc.shortcut_once(p_h, s2)
+        p_next = sc.shortcut_once(p_h, s2)  # one pass of p ← p[p]
         done = jnp.all(p_next == p_prev)
-        return p_next, total, msf_eids, n_f, it + 1, done
+        return p_next, msf_eids, msf_w, n_f, it + 1, jumps + 1, done
 
     return body_paper if variant == "paper" else body_complete
 
 
 def _msf_init(graph: Graph, parent0):
     if parent0 is None:
-        p0 = jnp.arange(graph.n, dtype=jnp.int32)
+        p0, k0 = jnp.arange(graph.n, dtype=jnp.int32), jnp.int32(0)
     else:
         # Canonicalize: the hooking kernels rely on the every-tree-a-star
         # invariant at the top of each iteration.
-        p0 = sc.complete_shortcut(parent0.astype(jnp.int32))
+        p0, k0 = sc.count_shortcut_subiters(parent0.astype(jnp.int32))
     return (
         p0,
-        jnp.float32(0.0),
         jnp.full((graph.n,), IMAX, jnp.int32),
+        jnp.zeros((graph.n,), jnp.float32),
         jnp.int32(0),
         jnp.int32(0),
+        k0,
         jnp.bool_(False),
     )
 
@@ -218,14 +245,16 @@ def _msf_jit(
     body = _make_msf_body(graph, variant, shortcut_fn, pack, segmin, ranks)
 
     def cond(state):
-        _, _, _, _, it, done = state
+        it, done = state[4], state[6]
         guard = it < limit if unroll_guard else True
         return jnp.logical_and(~done, guard)
 
     init = _msf_init(graph, parent0)
-    p, total, msf_eids, n_f, it, _ = jax.lax.while_loop(cond, body, init)
-    p = sc.complete_shortcut(p)  # canonical labels (complete variant: no-op)
-    return MSFResult(weight=total, parent=p, msf_eids=msf_eids, n_msf_edges=n_f, iterations=it)
+    p, msf_eids, msf_w, n_f, it, jumps, _ = jax.lax.while_loop(cond, body, init)
+    # canonical labels (complete variant: no-op, no jump)
+    p, k = sc.count_shortcut_subiters(p)
+    return MSFResult(parent=p, msf_eids=msf_eids, msf_w=msf_w, n_msf_edges=n_f,
+                     iterations=it, shortcut_jumps=jumps + k)
 
 
 def run_flat(graph: Graph, **kw) -> MSFResult:
@@ -236,7 +265,8 @@ def run_flat(graph: Graph, **kw) -> MSFResult:
     Every caller's first host read of the result is its edge count; it is
     made here, inside the span, so the span ends when the solve has run
     on the device (the value stays with the array, nothing is copied
-    twice)."""
+    twice). The pointer-jump count is read beside it, into the counter
+    ``msf.shortcut.jumps``."""
     from repro import obs
 
     if _ranked(kw.get("variant", "complete"), kw.get("pack", False)):
@@ -244,6 +274,7 @@ def run_flat(graph: Graph, **kw) -> MSFResult:
     with obs.span("msf.flat"):
         r = _msf_jit(graph, **kw)
         int(r.n_msf_edges)
+        obs.counter("msf.shortcut.jumps").inc(int(r.shortcut_jumps))
     return r
 
 

@@ -31,17 +31,20 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
+from repro.core.msf import FOREST_WEIGHT, record_edges
 from repro.core.multilinear import min_outgoing_2d, min_outgoing_2d_packed
 from repro.core.semiring import INF, IMAX
 from repro.graphs.partition import Partition2D
 
 
 class DistMSFResult(NamedTuple):
-    weight: jax.Array
     parent: jax.Array  # [n_pad] sharded
     msf_eids: jax.Array  # [n_pad] replicated, IMAX padded
+    msf_w: jax.Array  # [n_pad] replicated: each MSF edge's weight, 0 padded
     n_msf_edges: jax.Array
     iterations: jax.Array
+
+    weight = FOREST_WEIGHT
 
 
 def _csp_apply(keep, r_parent, p_local, n_pad, capacity):
@@ -191,7 +194,7 @@ def build_dist_driver(
     )
 
     def step(src_row, dst_col, w, eid, valid, p_local, state):
-        total, msf_eids, n_f, it = state
+        msf_eids, msf_w, n_f, it = state
         kernel = min_outgoing_2d_packed if pack else min_outgoing_2d
         r = kernel(
             p_local,
@@ -213,12 +216,8 @@ def build_dist_driver(
         tgt_parent = jnp.where(hooked[tgt], r_parent[tgt], tgt)
         t = hooked & (i < r_parent) & (tgt_parent == i)
         keep = hooked & ~t
-        total = total + jnp.sum(jnp.where(keep, r_w, 0.0))
         # Record MSF edges (replicated bookkeeping).
-        pos = n_f + jnp.cumsum(keep.astype(jnp.int32)) - 1
-        msf_eids = msf_eids.at[jnp.where(keep, pos, n_pad)].set(r_eid, mode="drop")
-        n_f = n_f + jnp.sum(keep.astype(jnp.int32))
-        #
+        msf_eids, msf_w, n_f = record_edges(msf_eids, msf_w, n_f, keep, r_eid, r_w)
 
         # Apply hooks to the local shard, then shortcut.
         shard_ix = _shard_index(row_axis, col_axis, part.cols)
@@ -247,7 +246,7 @@ def build_dist_driver(
             raise ValueError(f"unknown distributed shortcut {shortcut!r}")
 
         done = ~jnp.any(keep)
-        return p_next, (total, msf_eids, n_f, it + 1), done
+        return p_next, (msf_eids, msf_w, n_f, it + 1), done
 
     def run(src_row, dst_col, w, eid, valid, p0_local):
         src_row = src_row.reshape(src_row.shape[-1:])
@@ -257,8 +256,8 @@ def build_dist_driver(
         valid = valid.reshape(valid.shape[-1:])
 
         init_state = (
-            jnp.float32(0.0),
             jnp.full((n_pad,), IMAX, jnp.int32),
+            jnp.zeros((n_pad,), jnp.float32),
             jnp.int32(0),
             jnp.int32(0),
         )
@@ -274,8 +273,8 @@ def build_dist_driver(
 
         carry0 = (p0_local, init_state, jnp.bool_(False))
         p_loc, state, _ = lax.while_loop(cond_fn, body_fn, carry0)
-        total, msf_eids, n_f, it = state
-        return total, p_loc, msf_eids, n_f, it
+        msf_eids, msf_w, n_f, it = state
+        return p_loc, msf_eids, msf_w, n_f, it
 
     specs_edges = P(row_axis, col_axis, None)
     flat_axes = (
@@ -285,16 +284,16 @@ def build_dist_driver(
         run,
         mesh=mesh,
         in_specs=(specs_edges,) * 5 + (P(flat_axes),),
-        out_specs=(P(), P(flat_axes), P(), P(), P()),
+        out_specs=(P(flat_axes), P(), P(), P(), P()),
         check_vma=False,
     )
 
     @jax.jit
     def driver(src_row, dst_col, w, eid, valid):
         p0 = jnp.arange(n_pad, dtype=jnp.int32)
-        total, p, msf_eids, n_f, it = mapped(src_row, dst_col, w, eid, valid, p0)
+        p, msf_eids, msf_w, n_f, it = mapped(src_row, dst_col, w, eid, valid, p0)
         return DistMSFResult(
-            weight=total, parent=p, msf_eids=msf_eids, n_msf_edges=n_f, iterations=it
+            parent=p, msf_eids=msf_eids, msf_w=msf_w, n_msf_edges=n_f, iterations=it
         )
 
     return driver

@@ -46,7 +46,8 @@ def complete_shortcut(p: jax.Array) -> jax.Array:
 
 
 def count_shortcut_subiters(p: jax.Array):
-    """complete_shortcut that also reports sub-iteration count (benchmarks)."""
+    """complete_shortcut that also counts its p ← p[p] sub-iterations
+    (the flat driver's ``shortcut_jumps``)."""
 
     def cond(state):
         p, _ = state
@@ -107,9 +108,8 @@ def build_changed(p: jax.Array, p_prev: jax.Array, capacity: int):
     return ids, vals, count, count > capacity
 
 
-def csp_shortcut(p: jax.Array, p_prev: jax.Array, capacity: int) -> jax.Array:
-    """Algorithm 2, single-shard semantics (the distributed version replaces
-    ``build_changed`` with one all-gather)."""
+def _csp_counted(p: jax.Array, p_prev: jax.Array, capacity: int):
+    """:func:`csp_shortcut` and the p ← p[p] passes of its fallback."""
     ids, vals, _, overflow = build_changed(p, p_prev, capacity)
     ids, vals = _compress_changed_map(ids, vals)
     j = jnp.clip(jnp.searchsorted(ids, p), 0, ids.shape[0] - 1)
@@ -117,34 +117,50 @@ def csp_shortcut(p: jax.Array, p_prev: jax.Array, capacity: int) -> jax.Array:
     p_csp = jnp.where(hit, vals[j], p)
     # Overflow ⇒ the buffer silently dropped entries; fall back (OS policy
     # makes this explicit, but csp alone must stay correct).
-    return jax.lax.cond(overflow, complete_shortcut, lambda q: p_csp, p)
+    return jax.lax.cond(
+        overflow, count_shortcut_subiters, lambda q: (p_csp, jnp.int32(0)), p
+    )
 
 
-def optimized_shortcut(
-    p: jax.Array, p_prev: jax.Array, capacity: int
-) -> jax.Array:
-    """Paper's OS: invoke CSP only when |changed| ≤ capacity."""
+def csp_shortcut(p: jax.Array, p_prev: jax.Array, capacity: int) -> jax.Array:
+    """Algorithm 2, single-shard semantics (the distributed version replaces
+    ``build_changed`` with one all-gather)."""
+    return _csp_counted(p, p_prev, capacity)[0]
+
+
+def _os_counted(p: jax.Array, p_prev: jax.Array, capacity: int):
+    """:func:`optimized_shortcut` and the p ← p[p] passes of its plain
+    branch."""
     ids, vals, count, overflow = build_changed(p, p_prev, capacity)
 
     def use_csp(_):
         cids, cvals = _compress_changed_map(ids, vals)
         j = jnp.clip(jnp.searchsorted(cids, p), 0, cids.shape[0] - 1)
         hit = cids[j] == p
-        return jnp.where(hit, cvals[j], p)
+        return jnp.where(hit, cvals[j], p), jnp.int32(0)
 
     def use_plain(_):
-        return complete_shortcut(p)
+        return count_shortcut_subiters(p)
 
     return jax.lax.cond(overflow, use_plain, use_csp, None)
 
 
+def optimized_shortcut(
+    p: jax.Array, p_prev: jax.Array, capacity: int
+) -> jax.Array:
+    """Paper's OS: invoke CSP only when |changed| ≤ capacity."""
+    return _os_counted(p, p_prev, capacity)[0]
+
+
 def make_shortcut_fn(strategy: str, capacity: int = 1 << 16):
-    """strategy ∈ {baseline, complete, csp, os}. ``baseline`` = one jump
-    round (only valid inside the faithful AS variant which starchecks)."""
+    """``fn(p, p_prev) -> (p, jumps)`` for strategy ∈ {complete, csp, os}:
+    ``jumps`` counts the p ← p[p] passes over the whole parent vector
+    (complete shortcutting's sub-iterations; csp and os count only their
+    complete-shortcut fallback, not the steps inside the changed map)."""
     if strategy == "complete":
-        return lambda p, p_prev: complete_shortcut(p)
+        return lambda p, p_prev: count_shortcut_subiters(p)
     if strategy == "csp":
-        return partial(csp_shortcut, capacity=capacity)
+        return partial(_csp_counted, capacity=capacity)
     if strategy == "os":
-        return partial(optimized_shortcut, capacity=capacity)
+        return partial(_os_counted, capacity=capacity)
     raise ValueError(f"unknown shortcut strategy {strategy!r}")

@@ -44,7 +44,8 @@ class _FlatEngine:
             segmin=rs.segmin_flat,
         )
         with obs.span("solve.fetch"):  # the forest's copy to the host
-            return report_from_msf_result("flat", r)
+            return report_from_msf_result("flat", r,
+                                          shortcut_jumps=r.shortcut_jumps)
 
 
 def _build_flat(target, rs: ResolvedSpec, mesh):
@@ -76,7 +77,8 @@ class _CoarsenEngine:
         r = self._eng(graph)
         st = self._eng.last_stats
         return report_from_msf_result(
-            "coarsen", r, levels=st.levels if st is not None else ()
+            "coarsen", r, levels=st.levels if st is not None else (),
+            shortcut_jumps=r.shortcut_jumps,
         )
 
 

@@ -4,7 +4,8 @@ The deprecated entry points each reported a different type
 (``MSFResult`` / ``DistMSFResult`` / ``CoarsenStats`` /
 ``DistCoarsenStats`` / ``UpdateStats``); a :class:`SolveReport` carries
 the union of what callers actually consume — forest weight, the chosen
-global eids, component labels, iteration count, the per-level coarsening
+global eids, component labels, iteration count, the shortcut's pointer
+jumps, the per-level coarsening
 rows, the two operational counters (host round-trips, recompiles), and
 the analytic ``cost`` of the plan's executable
 (``repro.solve.cost.PlanCost``, computed once at ``plan.cost`` —
@@ -22,7 +23,7 @@ class SolveReport(NamedTuple):
     """Uniform result of ``Plan.solve()`` (and ``Plan.update()``)."""
 
     mode: str  # engine that produced this report
-    weight: float  # total forest weight
+    weight: float  # total forest weight: core.msf.forest_weight, float64 on the host
     msf_eids: np.ndarray  # int32 [n_msf_edges] chosen edge ids, trimmed
     parent: np.ndarray  # int32 [n] component representative per vertex
     n_msf_edges: int
@@ -34,6 +35,9 @@ class SolveReport(NamedTuple):
     cost: Any = None  # PlanCost of the plan's executable; None off-scope
     stale: bool = False  # stream mode: snapshot may diverge from true MSF
     n_unhealed: int = 0  # stream mode: deletions not certifiably healed
+    # p ← p[p] passes of the flat driver's shortcut (flat, and the coarsen
+    # residual's flat solve); 0 where no flat driver ran
+    shortcut_jumps: int = 0
 
     @property
     def n_components(self) -> int:
@@ -68,11 +72,13 @@ def report_from_msf_result(
     levels: Tuple = (),
     host_roundtrips: int = 0,
     recompiles: int = 0,
+    shortcut_jumps: int = 0,
 ) -> SolveReport:
-    """Adapt an ``MSFResult``/``DistMSFResult``-shaped record."""
+    """Adapt an ``MSFResult``/``DistMSFResult``-shaped record; its
+    ``weight`` is the host float64 sum of its recorded edge weights."""
     return SolveReport(
         mode=mode,
-        weight=float(r.weight),
+        weight=r.weight,
         msf_eids=_trim_eids(r.msf_eids, r.n_msf_edges),
         parent=np.asarray(r.parent),
         n_msf_edges=int(r.n_msf_edges),
@@ -81,4 +87,5 @@ def report_from_msf_result(
         host_roundtrips=int(host_roundtrips),
         recompiles=int(recompiles),
         raw=r,
+        shortcut_jumps=int(shortcut_jumps),
     )

@@ -50,7 +50,7 @@ from typing import NamedTuple
 import numpy as np
 
 from repro import obs
-from repro.core.msf import run_flat
+from repro.core.msf import forest_weight, run_flat
 from repro.core.semiring import PACK_IDX_MASK
 from repro.graphs.structures import Graph, edge_keys
 from repro.solve.spec import resolve_flat_segmin, weights_packable
@@ -787,7 +787,7 @@ class StreamEngine:
         """Exact live-row weight sum (float64 accumulate — the published
         weight is always recomputed from the rows, never decremented)."""
         live = ~self._dead[: self._count]
-        return float(self._w[: self._count][live].sum(dtype=np.float64))
+        return forest_weight(self._w[: self._count][live])
 
     def _union_graph(self, lo_u, hi_u, w_u, valid_u) -> Graph:
         local_eid = np.arange(len(lo_u), dtype=np.int32)

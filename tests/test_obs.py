@@ -381,3 +381,17 @@ def test_ranked_reduction_counter_counts_unpacked_flat_solves():
     plan(g, SolveSpec(pack=True)).solve()
     plan(g, SolveSpec(pack=False, variant="paper")).solve()
     assert obs.metrics_snapshot()["counters"]["msf.reduction.ranked"] == 2
+
+
+def test_shortcut_jump_counter_counts_per_flat_call():
+    """``msf.shortcut.jumps`` adds each ``run_flat`` call's pointer-jump
+    count, read beside the edge count."""
+    from repro.core.msf import run_flat
+    from repro.graphs.generators import grid_road_graph
+
+    want = 0
+    for g in (random_graph(128, 512, seed=4), grid_road_graph(16, 20, seed=1)):
+        r = run_flat(g)
+        assert int(r.shortcut_jumps) > 0
+        want += int(r.shortcut_jumps)
+        assert obs.metrics_snapshot()["counters"]["msf.shortcut.jumps"] == want
